@@ -118,6 +118,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from repro import obs
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     if args.metrics_dir:
         obs.configure(metrics_dir=args.metrics_dir)
     rows, failures = collect(args.tables, smoke=args.smoke)

@@ -32,6 +32,8 @@ import numpy as np
 from repro import quant
 from repro.core import lookup, lram
 from repro.distributed import context as _ctx
+from repro.kernels import interpret_mode
+from repro.launch.mesh import make_mesh
 from repro.memstore import TieredSpec
 
 M = 64
@@ -138,7 +140,7 @@ def measure(smoke: bool = False):
     rng = np.random.default_rng(0)
     dense = rng.normal(size=(p["num_rows"], M)).astype(np.float32) * 0.02
     dense_dev = jnp.asarray(dense)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     rows = []
     for placement, storage, kernel in _cells(smoke):
         cfg = _make_cfg(placement, storage, kernel, p)
@@ -166,7 +168,7 @@ def measure(smoke: bool = False):
             # jitted device cells are sub-ms dispatch-dominated calls —
             # give them 3x the samples so the median rides out scheduler
             # jitter (they are gated at 1.3x in CI)
-            if kernel == "pallas" and jax.default_backend() != "tpu":
+            if kernel == "pallas" and interpret_mode():
                 steps = 3
             elif not eager:
                 steps = 3 * p["steps"]

@@ -95,6 +95,17 @@ def get_smoke_config(name: str, **overrides) -> ModelConfig:
     return dataclasses.replace(cfg, **overrides) if overrides else cfg
 
 
+def with_placement(cfg: ModelConfig, placement: str) -> ModelConfig:
+    """`cfg` with its memory table's lookup placement
+    (`LRAMConfig.interp_impl`) replaced — the drivers' `--placement`."""
+    if cfg.lram is None:
+        raise SystemExit(f"--placement needs a memory arch; {cfg.name} "
+                         f"has no LRAM layer")
+    return dataclasses.replace(
+        cfg, lram=dataclasses.replace(cfg.lram, interp_impl=placement)
+    )
+
+
 def with_lram(cfg: ModelConfig, log2_locations: int = 20,
               layer: int | None = None) -> ModelConfig:
     """Insert the paper's memory-augmented FFN at one layer of any arch."""

@@ -20,7 +20,7 @@ Axes:
 * **storage** — how a row is stored: ``fp32`` | ``int8`` | ``fp8``
   (1-byte payload + per-row fp32 scales, ``repro.quant``).
 * **kernel** — how the gather executes: ``reference`` (jnp take+einsum)
-  | ``pallas`` (scalar-prefetch TPU kernels, interpret mode on CPU).
+  | ``pallas`` (DMA gather TPU kernels, interpret mode on CPU).
 
 Backends self-register: ``repro.kernels.ref`` / ``repro.kernels.
 gather_interp`` / ``repro.kernels.tiered_gather`` register gather kernels,

@@ -21,7 +21,7 @@ Implementation selection is a **plan** over three orthogonal axes
 tiered | sharded | sharded-tiered, with "reference"/"pallas" as dense
 aliases), storage (`LRAMConfig.table_quant` — fp32 | int8 | fp8 rows with
 per-row scales, `repro.quant`), and kernel (`LRAMConfig.lookup_kernel` —
-jnp reference or the Pallas scalar-prefetch kernels).  The plan is
+jnp reference or the Pallas DMA gather kernels).  The plan is
 resolved once at `lram_init`/trace time; it builds the value table
 (`params["values"]` — a dense array, `QuantizedTable`,
 `TieredValueStore`, or `ShardedTieredStore`) and owns the gather+interp

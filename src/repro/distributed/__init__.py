@@ -5,8 +5,7 @@ Public surface: sharding rules re-exported below (`MeshAxes`,
 module per concern — `repro.distributed.sharded_lram` (model-parallel
 LRAM lookup, quantization-aware), `pipeline` (GPipe over a mesh axis),
 `collectives` (compressed psum), `context` (mesh-scoped activation
-constraints), `fault` (heartbeat/straggler monitors), `_compat`
-(shard_map across jax versions).
+constraints), `fault` (heartbeat/straggler monitors).
 """
 
 from repro.distributed.sharding import (  # noqa: F401

@@ -23,7 +23,7 @@ Composition over the plan axes:
   scales over the same axis; each device dequantizes only the rows it
   gathers locally, and the psum'd fp32 partials are unchanged —
   quantization is invisible to the collective.
-* **kernel** — the shard-local gather can run the Pallas scalar-prefetch
+* **kernel** — the shard-local gather can run the Pallas DMA gather
   kernel (`kernel="pallas"`; `repro.kernels.gather_interp`) instead of
   jnp take+einsum.  The custom-VJP wrappers keep the sparse backward
   contract inside `shard_map`.
@@ -47,12 +47,13 @@ from typing import Iterable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import obs
 from repro.core import lookup
 from repro.distributed import context as _ctx
-from repro.distributed._compat import shard_map
+from repro.kernels import interpret_mode
 from repro.memstore.store import TieredSpec, TieredValueStore
 from repro.quant import QuantizedTable, dequantize_rows
 
@@ -80,7 +81,7 @@ def sharded_gather_interp(mesh: Mesh, *, axis: str = AXIS,
     other = tuple(a for a in mesh.axis_names if a != axis)
     act_spec = P(other if len(other) > 1 else (other[0] if other else None))
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
 
     def interp(values, idx, w):
         quantized = isinstance(values, QuantizedTable)

@@ -127,7 +127,11 @@ def _query_kernel(q_ref, cand_ref, aux_ref, idx_ref, w_ref,
     iota8 = jax.lax.broadcasted_iota(jnp.int32, t.shape, 0)
     keys, (tsort, perm) = _sort_rows_desc(jnp.abs(t), [t, iota8])
     sgn = jnp.where(tsort < 0, -1.0, 1.0)
-    parity = jnp.prod(sgn, axis=0, keepdims=True)
+    # product of the signs, from the count of negatives (Mosaic has no
+    # reduce_prod)
+    negatives = jnp.sum((tsort < 0).astype(jnp.float32), axis=0,
+                        keepdims=True)
+    parity = 1.0 - 2.0 * jnp.mod(negatives, 2.0)
     sgn = jnp.concatenate([sgn[:7], sgn[7:] * parity], axis=0)
     z = sgn * tsort                         # (8, B), lies in F
 

@@ -13,8 +13,8 @@ jax.custom_vjp pair:
         with the neighbor position k recovered from the stored index by
         nearest-image unwrapping on the torus.
 
-On this CPU container the Pallas path runs in interpret mode (set by
-`interpret=True`); on real TPUs the same code JITs to Mosaic.
+`interpret=True` runs the Pallas path in interpret mode (the CPU
+backend); with `interpret=False` the same code compiles to Mosaic.
 """
 
 from __future__ import annotations

@@ -3,45 +3,39 @@
 Companion to `repro.kernels.gather_interp`: same bandwidth-critical
 weighted gather, but the table operand is the *device cache* of a
 `repro.memstore.TieredValueStore` — (cache_slots * shard_rows, m) — and the
-global row id is translated on the fly through the shard->slot indirection
-table:
+global row id is translated through the shard->slot indirection table:
 
     cache_row(r) = slot_table[r >> log2(shard_rows)] * shard_rows
                    + (r & (shard_rows - 1))
 
-Both the flat index array AND the indirection table ride the scalar-prefetch
-mechanism: they land in SMEM before the kernel runs, so the BlockSpec
-index_map can chase the indirection and DMA exactly one cached value row
-HBM->VMEM per grid step.  The translation is a shift/mask/multiply on SMEM
-scalars — the grid sequencer hides it behind the row DMA, so indirection
-adds no per-step latency over the dense gather kernel.
+The translation is a shift/mask and a gather from a table of `num_shards`
+entries, done in XLA before the kernel; the kernel is the dense gather's
+DMA kernel run on the cache.
 
 All indices must be cache-resident (slot_table[shard] >= 0) — the store
 guarantees this by pinning the current batch's shards and serving overflow
 rows host-side before choosing this kernel.
-
-On CPU this runs in interpret mode; on real TPUs it JITs to Mosaic.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import lookup
+from repro.kernels.gather_interp import (
+    gather_interp_pallas,
+    gather_interp_quant_pallas,
+)
 
 
-def _kernel(idx_ref, slot_ref, w_ref, row_ref, out_ref):
-    del idx_ref, slot_ref  # consumed by the index_map
-    k = pl.program_id(1)
-
-    @pl.when(k == 0)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += w_ref[0, k] * row_ref[...].astype(out_ref.dtype)
+def _cache_rows(idx, slot_table, shard_rows: int) -> jax.Array:
+    if shard_rows & (shard_rows - 1):
+        raise ValueError("shard_rows must be a power of two")
+    log2r = shard_rows.bit_length() - 1
+    idx = idx.astype(jnp.int32)
+    slot = jnp.take(slot_table.astype(jnp.int32), idx >> log2r, axis=0)
+    return slot * shard_rows + (idx & (shard_rows - 1))
 
 
 def tiered_gather_pallas(
@@ -62,54 +56,10 @@ def tiered_gather_pallas(
       w: (..., top_k) interpolation weights.
       shard_rows: rows per shard (power of two; fixes the shift/mask).
     """
-    if shard_rows & (shard_rows - 1):
-        raise ValueError("shard_rows must be a power of two")
-    log2r = shard_rows.bit_length() - 1
-    lead = idx.shape[:-1]
-    top_k = idx.shape[-1]
-    m = cache_flat.shape[-1]
-    idx_flat = idx.reshape(-1, top_k).astype(jnp.int32)
-    w_flat = w.reshape(-1, top_k).astype(jnp.float32)
-    n = idx_flat.shape[0]
-
-    def _row_index(t, k, idx_sref, slot_sref):
-        gid = idx_sref[t, k]
-        slot = slot_sref[gid >> log2r]
-        return (slot * shard_rows + (gid & (shard_rows - 1)), 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n, top_k),
-        in_specs=[
-            pl.BlockSpec((1, top_k), lambda t, k, idx_sref, slot_sref: (t, 0)),
-            pl.BlockSpec((1, m), _row_index),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, m), lambda t, k, idx_sref, slot_sref: (t, 0)
-        ),
-    )
-    out = pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, m), jnp.float32),
+    return gather_interp_pallas(
+        cache_flat, _cache_rows(idx, slot_table, shard_rows), w,
         interpret=interpret,
-    )(idx_flat, slot_table.astype(jnp.int32), w_flat, cache_flat)
-    return out.reshape(*lead, m)
-
-
-def _kernel_quant(idx_ref, slot_ref, w_ref, row_ref, scale_ref, out_ref):
-    del idx_ref, slot_ref  # consumed by the index_map
-    k = pl.program_id(1)
-
-    @pl.when(k == 0)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    # fused dequant: the cached row arrives in its 1-byte form; its fp32
-    # scale rides a (1, 1) block through the same indirected index_map, so
-    # the accumulate stays fp32 while the row DMA shrinks 4x
-    out_ref[...] += (w_ref[0, k] * scale_ref[0, 0]) \
-        * row_ref[...].astype(out_ref.dtype)
+    )
 
 
 def tiered_gather_quant_pallas(
@@ -131,41 +81,10 @@ def tiered_gather_quant_pallas(
       scale_flat: (cache_slots * shard_rows,) fp32 per-row scales.
       idx / slot_table / w / shard_rows: as in `tiered_gather_pallas`.
     """
-    if shard_rows & (shard_rows - 1):
-        raise ValueError("shard_rows must be a power of two")
-    log2r = shard_rows.bit_length() - 1
-    lead = idx.shape[:-1]
-    top_k = idx.shape[-1]
-    m = cache_flat.shape[-1]
-    idx_flat = idx.reshape(-1, top_k).astype(jnp.int32)
-    w_flat = w.reshape(-1, top_k).astype(jnp.float32)
-    scale_col = scale_flat.reshape(-1, 1).astype(jnp.float32)
-    n = idx_flat.shape[0]
-
-    def _row_index(t, k, idx_sref, slot_sref):
-        gid = idx_sref[t, k]
-        slot = slot_sref[gid >> log2r]
-        return (slot * shard_rows + (gid & (shard_rows - 1)), 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n, top_k),
-        in_specs=[
-            pl.BlockSpec((1, top_k), lambda t, k, idx_sref, slot_sref: (t, 0)),
-            pl.BlockSpec((1, m), _row_index),
-            pl.BlockSpec((1, 1), _row_index),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, m), lambda t, k, idx_sref, slot_sref: (t, 0)
-        ),
-    )
-    out = pl.pallas_call(
-        _kernel_quant,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, m), jnp.float32),
+    return gather_interp_quant_pallas(
+        cache_flat, scale_flat, _cache_rows(idx, slot_table, shard_rows), w,
         interpret=interpret,
-    )(idx_flat, slot_table.astype(jnp.int32), w_flat, cache_flat, scale_col)
-    return out.reshape(*lead, m)
+    )
 
 
 def tiered_gather_ref(

@@ -44,7 +44,6 @@ plus per-step latencies, p50/p99, tokens/sec, and per-request records.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import os
 
@@ -53,6 +52,7 @@ import numpy as np
 
 from repro import configs, memctl, obs
 from repro.checkpoint import CheckpointManager
+from repro.launch.cache import use_compile_cache
 from repro.models import transformer
 from repro.serving import EngineConfig, ServeEngine, synthetic_trace
 
@@ -133,6 +133,7 @@ def build_argparser() -> argparse.ArgumentParser:
 
 def main(argv=None):
     args = build_argparser().parse_args(argv)
+    use_compile_cache()
     if args.metrics_dir:
         obs.configure(metrics_dir=args.metrics_dir,
                       profile_dir=args.profile_dir or None)
@@ -140,13 +141,7 @@ def main(argv=None):
            else configs.get_config(args.arch))
 
     if args.placement:
-        if cfg.lram is None:
-            raise SystemExit(f"--placement needs a memory arch; {cfg.name} "
-                             f"has no LRAM layer")
-        cfg = dataclasses.replace(
-            cfg, lram=dataclasses.replace(cfg.lram,
-                                          interp_impl=args.placement)
-        )
+        cfg = configs.with_placement(cfg, args.placement)
 
     rng = np.random.default_rng(args.seed)
     key = jax.random.PRNGKey(args.seed)

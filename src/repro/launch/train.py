@@ -25,8 +25,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs, data, memctl, obs, optim
 from repro.checkpoint import CheckpointManager
 from repro.core import lookup
-from repro.distributed import fault, sharding
+from repro.distributed import context, fault, sharding
 from repro.launch import mesh as mesh_lib
+from repro.launch.cache import use_compile_cache
 from repro.models import transformer
 
 
@@ -112,26 +113,30 @@ def build_train_step(cfg, opt_cfg, mesh=None, compression="none",
 
 
 def evaluate(params, model_state, cfg, dcfg, *, steps=4):
-    losses, recalls = [], []
+    """Held-out loss, and fact recall on a probe set.  Both run jitted at
+    the training batch size: the lattice lookup's (tokens, 232, 8)
+    neighbour tensor of a larger batch does not fit one chip's HBM."""
+    loss_fn = jax.jit(lambda p, s, b: transformer.loss_fn(
+        p, s, b, cfg, train=False)[0])
+    forward = jax.jit(lambda p, s, b: transformer.forward(p, s, b, cfg)[0])
+    losses = []
     table = data.make_fact_table(dcfg)
     for i in range(steps):
         batch = jax.tree.map(
             jnp.asarray, data.get_batch(dcfg, step=10_000_000 + i,
                                         table=table)
         )
-        loss, (_, m) = transformer.loss_fn(
-            params, model_state, batch, cfg, train=False
-        )
-        losses.append(float(loss))
-    probe = jax.tree.map(jnp.asarray,
-                         data.synthetic.fact_eval_batch(dcfg, n=64,
-                                                        table=table))
-    logits, _, _ = transformer.forward(params, model_state, probe, cfg)
-    pred = jnp.argmax(logits, axis=-1)
-    mask = probe["labels"] != data.synthetic.IGNORE
-    recall = float((jnp.where(mask, pred == probe["labels"], False)).sum()
-                   / mask.sum())
-    return float(np.mean(losses)), recall
+        losses.append(float(loss_fn(params, model_state, batch)))
+    probe = data.synthetic.fact_eval_batch(dcfg, n=64, table=table)
+    hits = total = 0
+    for lo in range(0, len(probe["tokens"]), dcfg.global_batch):
+        chunk = {k: jnp.asarray(v[lo:lo + dcfg.global_batch])
+                 for k, v in probe.items()}
+        pred = jnp.argmax(forward(params, model_state, chunk), axis=-1)
+        mask = chunk["labels"] != data.synthetic.IGNORE
+        hits += int(jnp.where(mask, pred == chunk["labels"], False).sum())
+        total += int(mask.sum())
+    return float(np.mean(losses)), hits / total
 
 
 def main(argv=None):
@@ -169,7 +174,15 @@ def main(argv=None):
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--use-mesh", action="store_true",
                    help="shard over all available devices")
+    p.add_argument("--placement", default="",
+                   choices=["", "reference", "pallas", "tiered", "sharded",
+                            "sharded-tiered"],
+                   help="override the memory arch's lookup placement "
+                        "(LRAMConfig.interp_impl) — e.g. 'sharded' with "
+                        "--use-mesh row-shards the table over the 'model' "
+                        "mesh axis")
     args = p.parse_args(argv)
+    use_compile_cache()
 
     if args.metrics_dir:
         obs.configure(metrics_dir=args.metrics_dir,
@@ -177,6 +190,8 @@ def main(argv=None):
 
     cfg = (configs.get_smoke_config(args.arch) if args.smoke
            else configs.get_config(args.arch))
+    if args.placement:
+        cfg = configs.with_placement(cfg, args.placement)
     if args.telemetry and cfg.lram is None:
         raise SystemExit(f"--telemetry needs a memory arch; {cfg.name} "
                          f"has no LRAM layer")
@@ -191,7 +206,20 @@ def main(argv=None):
     if args.use_mesh and jax.device_count() > 1:
         mesh = mesh_lib.make_host_mesh()
         print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))}")
+    # the ambient mesh (the `sharded` placement shard_maps over it) holds
+    # for this run only
+    prev_mesh = context.get_mesh()
+    if mesh is not None:
+        context.set_mesh(mesh)
+    try:
+        return _train(args, cfg, dcfg, opt_cfg, mesh)
+    finally:
+        context.set_mesh(prev_mesh)
 
+
+def _train(args, cfg, dcfg, opt_cfg, mesh):
+    """The loop behind `main`; returns (params, history), one history
+    entry {step, loss, grad_norm} per logged step."""
     key = jax.random.PRNGKey(args.seed)
     params, model_state = transformer.init(key, cfg)
 
@@ -265,6 +293,7 @@ def main(argv=None):
     tel = init_telemetry(cfg) if args.telemetry else None
     monitor = fault.HeartbeatMonitor(num_hosts=jax.process_count())
     timer = fault.StepTimer()
+    history = []
 
     for step in range(start_step, args.steps):
         if controller is not None:
@@ -318,6 +347,8 @@ def main(argv=None):
         monitor.heartbeat(jax.process_index(), dt)
         if step % args.log_every == 0 or step == args.steps - 1:
             slow = " STRAGGLER" if timer.is_outlier(dt) else ""
+            history.append({"step": step, "loss": float(metrics["loss"]),
+                            "grad_norm": float(metrics["grad_norm"])})
             rec = {
                 "step": step,
                 "loss": round(float(metrics["loss"]), 4),
@@ -363,7 +394,7 @@ def main(argv=None):
                       "final_fact_recall": round(recall, 4)}))
     if args.metrics_dir:
         obs.flush()
-    return params
+    return params, history
 
 
 if __name__ == "__main__":

@@ -61,6 +61,7 @@ import numpy as np
 
 from repro import obs, quant
 from repro.core import lookup
+from repro.kernels import interpret_mode
 
 
 @dataclasses.dataclass(frozen=True)
@@ -424,7 +425,7 @@ class TieredValueStore:
         w_flat = jnp.asarray(w).reshape(-1, top_k).astype(jnp.float32)
         sr = jnp.asarray(slot_rows.reshape(-1, top_k).astype(np.int32))
         if self.spec.use_pallas and mask.all():
-            interpret = jax.default_backend() != "tpu"
+            interpret = interpret_mode()
             idx_dev = jnp.asarray(flat.reshape(-1, top_k).astype(np.int32))
             slot_dev = jnp.asarray(self._shard_slot)
             if quantized:

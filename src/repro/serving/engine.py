@@ -119,6 +119,7 @@ class FinishedRequest:
     decode_steps: int
     cache_hit_rate: float | None
     first_logits: np.ndarray | None = None   # (V,) — equivalence testing
+    prompt: np.ndarray | None = None         # (prompt_len,) — likewise
 
     def summary(self) -> dict[str, Any]:
         return {
@@ -438,6 +439,7 @@ class ServeEngine:
                             if self.stores and total else
                             (0.0 if self.stores else None)),
             first_logits=slot.first_logits,
+            prompt=slot.request.prompt,
         )
 
     def _done(self, slot: _Slot) -> bool:

@@ -51,11 +51,12 @@ def test_step_timer_outliers():
 def test_sharded_lram_lookup_matches_reference():
     run_in_subprocess(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import indexing, lram
         from repro.distributed.sharded_lram import sharded_gather_interp
         from repro.kernels import ref
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         spec = indexing.choose_torus(16)
         rng = np.random.default_rng(0)
         values = jnp.asarray(rng.normal(size=(spec.num_locations, 16))
@@ -85,9 +86,10 @@ def test_sharded_lram_lookup_matches_reference():
 def test_pipeline_parallel_matches_sequential():
     run_in_subprocess(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.distributed.pipeline import pipeline_apply
 
-        mesh = jax.make_mesh((4,), ("pod",))
+        mesh = make_mesh((4,), ("pod",))
         rng = np.random.default_rng(0)
         n_stages, d = 4, 16
         Ws = jnp.asarray(rng.normal(size=(n_stages, d, d))
@@ -112,11 +114,12 @@ def test_pipeline_parallel_matches_sequential():
 def test_compressed_psum_close_to_exact():
     run_in_subprocess(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
-        from repro.distributed._compat import shard_map
+        from jax import shard_map
         from repro.distributed.collectives import compressed_psum
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.normal(size=(8, 64)).astype(np.float32))
 
@@ -137,6 +140,7 @@ def test_compressed_psum_close_to_exact():
 def test_sharded_train_step_matches_single_device():
     run_in_subprocess(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro import configs, data, optim
         from repro.distributed import sharding
         from repro.launch.train import build_train_step
@@ -157,7 +161,7 @@ def test_sharded_train_step_matches_single_device():
 
         # sharded over 4x2 mesh (same PRNG key -> identical init)
         params2, _ = transformer.init(key, cfg)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         ps = sharding.shard_params(params2, mesh)
         stepm = build_train_step(cfg, opt_cfg, mesh)
         p2, o2, _, _, m2 = stepm(ps, optim.adam_init(ps), mstate,
@@ -178,6 +182,7 @@ def test_elastic_checkpoint_reshape():
     run_in_subprocess(textwrap.dedent("""
         import tempfile
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import CheckpointManager
         from repro.distributed import sharding
@@ -186,13 +191,13 @@ def test_elastic_checkpoint_reshape():
         d = tempfile.mkdtemp()
         mgr = CheckpointManager(d)
 
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_mesh((4, 2), ("data", "model"))
         sharded = jax.device_put(
             tree["w"], NamedSharding(mesh_a, P("data", "model")))
         mgr.save(1, {"w": sharded})
 
         # restore onto a DIFFERENT mesh shape (elastic rescale 8 -> 2x4)
-        mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_b = make_mesh((2, 4), ("data", "model"))
         target = NamedSharding(mesh_b, P("model", "data"))
         step, restored = mgr.restore({"w": tree["w"]},
                                      sharding={"w": target})

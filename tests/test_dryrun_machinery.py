@@ -14,6 +14,7 @@ def test_lower_compile_and_analyze_all_modes():
     run_in_subprocess(textwrap.dedent("""
         import dataclasses
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro import configs, optim
         from repro.analysis import hlo as hlo_lib
@@ -21,7 +22,7 @@ def test_lower_compile_and_analyze_all_modes():
         from repro.distributed import sharding
         from repro.models import transformer
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = configs.get_smoke_config("yi-9b")
         params, state = jax.eval_shape(
             lambda: transformer.init(jax.random.PRNGKey(0), cfg))
@@ -87,7 +88,7 @@ def test_lower_compile_and_analyze_all_modes():
         print("decode OK")
 
         # ---- multi-pod-style 3-axis mesh ----
-        mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
         pspecs3 = sharding.param_pspecs(params, mesh3)
         p3 = jax.tree.map(
             lambda sd, sp: jax.ShapeDtypeStruct(

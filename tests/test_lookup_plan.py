@@ -18,6 +18,7 @@ from repro.checkpoint import CheckpointManager
 from repro.core import lookup, lram
 from repro.distributed import context as _ctx
 from repro.distributed.sharded_lram import ShardedTieredStore
+from repro.launch.mesh import make_mesh
 from repro.memstore import TieredSpec, TieredValueStore
 
 KEY = jax.random.PRNGKey(0)
@@ -52,7 +53,7 @@ def model_mesh():
     """A 1-device mesh with a 'model' axis: enough to resolve and run the
     sharded placements in-process (the 8-fake-device equivalence lives in
     the slow subprocess tests)."""
-    return jax.make_mesh((1,), ("model",))
+    return make_mesh((1,), ("model",))
 
 
 @pytest.fixture(scope="module")
@@ -265,11 +266,12 @@ def test_sharded_pallas_and_quant_cells_on_real_mesh():
     (within the quant bound for int8), jit + grad included."""
     run_in_subprocess(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro import quant
         from repro.core import lookup, lram
         from repro.distributed import context as _ctx
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         _ctx.set_mesh(mesh)
         KEY = jax.random.PRNGKey(0)
         kw = dict(log2_locations=16, m=8, heads=2, query_norm="rms")

@@ -16,6 +16,7 @@ from repro import configs, memctl, quant
 from repro.core import indexing, lookup, lram
 from repro.distributed import context as _ctx, sharding
 from repro.distributed.sharded_lram import ShardedTieredStore
+from repro.launch.mesh import make_mesh
 from repro.memstore import TieredSpec, TieredValueStore
 from repro.models import transformer
 
@@ -131,7 +132,7 @@ def test_grow_rejects_bad_sizes_and_sharded():
         memctl.grow(params, cfg, 2 ** 16 + 4096)
     with pytest.raises(ValueError, match="grow"):
         memctl.grow(params, cfg, 2 ** 15)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     _ctx.set_mesh(mesh)
     try:
         cfg_sh = lram.LRAMConfig(**KW, interp_impl="sharded")
@@ -317,7 +318,7 @@ def test_migration_cross_storage_within_bound(rng):
 def test_migration_rejects_mesh_and_resize():
     cfg = make_cfg("dense", "fp32")
     params, _ = lram.lram_init(KEY, cfg)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     _ctx.set_mesh(mesh)
     try:
         with pytest.raises(lookup.LookupPlanError, match="migrate"):
@@ -507,7 +508,7 @@ def test_param_pspecs_plan_driven_memory_tables():
     """The resolved plan emits the memory table's pspec: replicated for
     dense placements, rows over `model` for the sharded placement — the
     regex rule for lram values is gone."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg_dense = configs.get_smoke_config("lram-tiered")
     cfg_dense = dataclasses.replace(
         cfg_dense, lram=dataclasses.replace(cfg_dense.lram,

@@ -18,6 +18,7 @@ import pytest
 from _hypo import given, settings, st
 from repro import configs, memctl, quant
 from repro.core import lookup, lram
+from repro.launch.mesh import make_mesh
 from repro.memstore import TieredSpec, TieredValueStore
 from repro.models import transformer
 from repro.models.config import ModelConfig
@@ -403,7 +404,7 @@ def test_supports_overlay_capability_matrix():
         tiered=TieredSpec(shard_rows=2048, cache_slots=2),
     )
     assert lookup.resolve(shti).supports_overlay
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     from repro.distributed import context as _ctx
     _ctx.set_mesh(mesh)
     try:

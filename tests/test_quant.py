@@ -128,11 +128,12 @@ def test_quantized_sharded_lookup_matches_reference():
     rows and psums fp32 partials — same bound, jit + grad, 8 fake devices."""
     run_in_subprocess(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro import quant
         from repro.core import indexing, lram
         from repro.distributed.sharded_lram import sharded_gather_interp
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         spec = indexing.choose_torus(16)
         rng = np.random.default_rng(0)
         values = rng.normal(size=(spec.num_locations, 16)) \\

@@ -93,7 +93,7 @@ def plan(cmd: list[str], level: str) -> list[str] | None:
     if cmd[:2] == ["python", "-m"]:
         module, args = cmd[2], cmd[3:]
     else:
-        return None  # `python path/to/script.py` is not documented today
+        return None  # `python chip_smoke.py` needs a TPU: not run here
     if module in ("pytest", "pip"):
         return None
     if module == "benchmarks.run":
