@@ -1,0 +1,2 @@
+"""`device_idle.chat`: 1 - device busy time / traced window, in %."""
+from _common import idle_percent as read  # noqa: F401
