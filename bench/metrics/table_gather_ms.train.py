@@ -1,0 +1,10 @@
+"""`table_gather_ms.train`: device time a step of the ops traced under
+`lram/gather` (the value table's row gather and weighted sum, and the
+scatter-add of its gradient), in ms, found by scope in the step's HLO
+(`bench/scopes.py`).  A fusion that spans two scopes counts in its root's.
+No match: no reading."""
+from scopes import scope_ms_per_step
+
+
+def read(ctx):
+    return scope_ms_per_step(ctx, "lram/gather")

@@ -303,26 +303,32 @@ def neighbors_and_weights(
     Differentiable in q almost everywhere: the isometry (decode / perm /
     signs) is locally constant, distances are computed in the canonical
     frame where they are smooth functions of q.
+
+    Its phases run under the named scopes `decode` (nearest point and
+    canonical frame), `distance` and `undo_isometry`.
     """
     cand, cand_nsq = candidate_arrays()
     cand = jnp.asarray(cand, dtype=q.dtype)
     cand_nsq = jnp.asarray(cand_nsq, dtype=q.dtype)
-    c = decode(q)
-    z, perm, sgn = canonicalize(q - c)
-    # squared distances to all candidates via one (..., 8) @ (8, 232) matmul
-    d2 = (
-        jnp.sum(z * z, axis=-1, keepdims=True)
-        - 2.0 * (z @ cand.T)
-        + cand_nsq
-    )
-    w = kernel_from_sq(d2)
-    # undo the isometry:  k[perm_j] = sgn_j * p_j + c[perm_j]
-    inv = jnp.argsort(perm, axis=-1, stable=True)
-    sp = sgn[..., None, :] * cand  # (..., 232, 8)
-    glob = jnp.take_along_axis(
-        sp, jnp.broadcast_to(inv[..., None, :], sp.shape), axis=-1
-    )
-    neighbors = c[..., None, :] + glob
+    with jax.named_scope("decode"):
+        c = decode(q)
+        z, perm, sgn = canonicalize(q - c)
+    with jax.named_scope("distance"):
+        # squared distances to all candidates: one (..., 8) @ (8, 232) matmul
+        d2 = (
+            jnp.sum(z * z, axis=-1, keepdims=True)
+            - 2.0 * (z @ cand.T)
+            + cand_nsq
+        )
+        w = kernel_from_sq(d2)
+    with jax.named_scope("undo_isometry"):
+        # k[perm_j] = sgn_j * p_j + c[perm_j]
+        inv = jnp.argsort(perm, axis=-1, stable=True)
+        sp = sgn[..., None, :] * cand  # (..., 232, 8)
+        glob = jnp.take_along_axis(
+            sp, jnp.broadcast_to(inv[..., None, :], sp.shape), axis=-1
+        )
+        neighbors = c[..., None, :] + glob
     return neighbors, w
 
 

@@ -126,29 +126,36 @@ def indices_and_weights(
       (87 GiB/step at pod scale — EXPERIMENTS.md §Perf cell 3);
       argmax/where/sum are trivially shard-local, and indices for all 232
       candidates are computed up front and selected by an exact integer
-      one-hot reduction — no sorts, no gathers."""
+      one-hot reduction — no sorts, no gathers.
+
+    Besides `neighbors_and_weights`' scopes, the selection runs under the
+    named scope `top_k` and the lattice-point encoding under `encode`."""
     from repro.distributed import context as _ctx
 
     nbrs, w = lattice.neighbors_and_weights(q)  # (...,232,8), (...,232)
     if _ctx.get_mesh() is None:
         # host path: sort-based top_k is fastest on a single device
-        w_top, sel = jax.lax.top_k(w, top_k)
-        nb_top = jnp.take_along_axis(
-            nbrs, sel[..., None].astype(jnp.int32), axis=-2
-        )
-        return indexing.encode_points(nb_top, spec), w_top
-    idx_all = indexing.encode_points(nbrs, spec)  # (..., 232) int32
-    iota = jax.lax.broadcasted_iota(jnp.int32, w.shape, w.ndim - 1)
-    scores = w
-    idxs, ws = [], []
-    for _ in range(top_k):
-        m = jnp.max(scores, axis=-1)
-        am = jnp.argmax(scores, axis=-1)
-        hit = iota == am[..., None]
-        idxs.append(jnp.sum(jnp.where(hit, idx_all, 0), axis=-1))
-        ws.append(m)
-        scores = jnp.where(hit, -1.0, scores)
-    return jnp.stack(idxs, axis=-1), jnp.stack(ws, axis=-1)
+        with jax.named_scope("top_k"):
+            w_top, sel = jax.lax.top_k(w, top_k)
+            nb_top = jnp.take_along_axis(
+                nbrs, sel[..., None].astype(jnp.int32), axis=-2
+            )
+        with jax.named_scope("encode"):
+            return indexing.encode_points(nb_top, spec), w_top
+    with jax.named_scope("encode"):
+        idx_all = indexing.encode_points(nbrs, spec)  # (..., 232) int32
+    with jax.named_scope("top_k"):
+        iota = jax.lax.broadcasted_iota(jnp.int32, w.shape, w.ndim - 1)
+        scores = w
+        idxs, ws = [], []
+        for _ in range(top_k):
+            m = jnp.max(scores, axis=-1)
+            am = jnp.argmax(scores, axis=-1)
+            hit = iota == am[..., None]
+            idxs.append(jnp.sum(jnp.where(hit, idx_all, 0), axis=-1))
+            ws.append(m)
+            scores = jnp.where(hit, -1.0, scores)
+        return jnp.stack(idxs, axis=-1), jnp.stack(ws, axis=-1)
 
 
 def gather_interp(values: jax.Array, idx: jax.Array, w: jax.Array) -> jax.Array:
@@ -216,6 +223,17 @@ def lram_apply(
     if x.shape[-1] != cfg.in_dim:
         raise ValueError(f"LRAM expects {cfg.in_dim} features, got {x.shape}")
     plan = lookup.resolve(cfg, interp_impl)
+    with jax.named_scope("lram"):
+        y, new_state, idx, w = _lram_body(params, state, x, cfg, plan, train)
+    if return_access:
+        return y, new_state, (idx, w)
+    return y, new_state
+
+
+def _lram_body(params, state, x, cfg: LRAMConfig, plan, train: bool):
+    """`lram_apply` under its `lram` scope.  Each phase has a scope of its
+    own (`query_norm`, `query`, `gather`, `overlay`): compiled ops carry it
+    in their `op_name` metadata, so a device trace is read by phase."""
     lead = x.shape[:-1]
     xh = x.reshape(*lead, cfg.heads, 2 * lattice.DIM)
     # heads ride the tensor-parallel axis (table shared/replicated): the
@@ -226,32 +244,35 @@ def lram_apply(
                + ("model", None) )
     )
     new_state = dict(state)
-    if cfg.query_norm == "batch":
-        xh, new_state["qnorm"] = nn.batchnorm(
-            params["qnorm"], state["qnorm"], xh, train=train
-        )
-    elif cfg.query_norm == "rms":
-        xh = nn.rmsnorm(params["qnorm"], xh)
+    with jax.named_scope("query_norm"):
+        if cfg.query_norm == "batch":
+            xh, new_state["qnorm"] = nn.batchnorm(
+                params["qnorm"], state["qnorm"], xh, train=train
+            )
+        elif cfg.query_norm == "rms":
+            xh = nn.rmsnorm(params["qnorm"], xh)
 
     spec = cfg.torus_spec
-    q, scale = torus.torus_map(xh.astype(jnp.float32), spec.K)
-    idx, w = indices_and_weights(q, spec, cfg.top_k)
-    out = plan.interp(params["values"], idx, w)
+    with jax.named_scope("query"):
+        q, scale = torus.torus_map(xh.astype(jnp.float32), spec.K)
+        idx, w = indices_and_weights(q, spec, cfg.top_k)
+    with jax.named_scope("gather"):
+        out = plan.interp(params["values"], idx, w)
     # per-tenant overlay (serve engine): correct rows the tenant has
     # overwritten, and record the access for the decode-step writeback.
     # Trace-time only — `current()` is None outside an engine overlay
     # context, and jit never re-runs this Python on cached calls.
     octx = overlay.current()
     if octx is not None:
-        out = octx.apply(idx, w, out)
+        with jax.named_scope("overlay"):
+            out = octx.apply(idx, w, out)
     # (..., heads, m)
     out = out * scale
     if octx is not None:
-        octx.record(idx, w, out)
+        with jax.named_scope("overlay"):
+            octx.record(idx, w, out)
     y = out.reshape(*lead, cfg.out_dim).astype(x.dtype)
-    if return_access:
-        return y, new_state, (idx, w)
-    return y, new_state
+    return y, new_state, idx, w
 
 
 # ---------------------------------------------------------------------------

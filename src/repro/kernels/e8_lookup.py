@@ -207,6 +207,7 @@ def lram_query_pallas(
             jax.ShapeDtypeStruct((qf.shape[0], top_k), jnp.float32),
         ],
         interpret=interpret,
+        name="lram_e8_query",
     )(qf, cand, aux)
     idx = idx[:n].reshape(*lead, top_k)
     w = w[:n].reshape(*lead, top_k)

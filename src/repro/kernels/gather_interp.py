@@ -131,11 +131,13 @@ def gather_interp_pallas(
     w: jax.Array,
     *,
     interpret: bool = False,
+    name: str = "lram_gather",
 ) -> jax.Array:
     """sum_k w[..., k] * values[idx[..., k]] -> (..., m) fp32.
 
     Non-differentiable by itself; `gather_interp_vjp` adds the custom_vjp
-    (scatter-add for dvalues, gathered dot for dw).
+    (scatter-add for dvalues, gathered dot for dw).  `name` is the
+    kernel's name in the compiled program and in a profile.
     """
     lead = idx.shape[:-1]
     top_k = idx.shape[-1]
@@ -170,6 +172,7 @@ def gather_interp_pallas(
         out_shape=jax.ShapeDtypeStruct((n + pad, planes * _LANES),
                                        jnp.float32),
         interpret=interpret,
+        name=name,
     )(idx_flat, w_flat, view)
     # fold the row segments, then interleave the planes: word i, plane b
     # holds element planes * i + b of the row
@@ -188,6 +191,7 @@ def gather_interp_quant_pallas(
     w: jax.Array,
     *,
     interpret: bool = False,
+    name: str = "lram_gather_quant",
 ) -> jax.Array:
     """sum_k w[..., k] * scale[i] * q[i := idx[..., k]] -> (..., m).
 
@@ -200,7 +204,8 @@ def gather_interp_quant_pallas(
     ws = w.astype(jnp.float32) * jnp.take(
         scale.astype(jnp.float32), idx_c, axis=0
     )
-    return gather_interp_pallas(q, idx_c, ws, interpret=interpret)
+    return gather_interp_pallas(q, idx_c, ws, interpret=interpret,
+                                name=name)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))

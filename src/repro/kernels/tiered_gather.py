@@ -58,7 +58,7 @@ def tiered_gather_pallas(
     """
     return gather_interp_pallas(
         cache_flat, _cache_rows(idx, slot_table, shard_rows), w,
-        interpret=interpret,
+        interpret=interpret, name="lram_tiered_gather",
     )
 
 
@@ -83,7 +83,7 @@ def tiered_gather_quant_pallas(
     """
     return gather_interp_quant_pallas(
         cache_flat, scale_flat, _cache_rows(idx, slot_table, shard_rows), w,
-        interpret=interpret,
+        interpret=interpret, name="lram_tiered_gather_quant",
     )
 
 

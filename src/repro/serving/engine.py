@@ -415,6 +415,7 @@ class ServeEngine:
             first_logits = np.asarray(logits[0, s - 1])
         prefill_s = time.perf_counter() - t0
         obs.counter("serve.admitted").inc()
+        obs.histogram("serve.queue_wait_s").observe(now - req.arrival_s)
         obs.histogram("serve.prefill_s").observe(prefill_s)
         first_tok = int(np.argmax(first_logits))
         return _Slot(
@@ -426,7 +427,9 @@ class ServeEngine:
         st = slot.stats
         total = sum(st.values())
         obs.counter("serve.retired").inc()
-        obs.histogram("serve.request_latency_s").observe(now - slot.admit_s)
+        # on the engine's clock, from arrival: queueing counts
+        obs.histogram("serve.request_latency_s").observe(
+            now - slot.request.arrival_s)
         return FinishedRequest(
             id=slot.request.id,
             prompt_len=slot.request.prompt_len,

@@ -313,6 +313,30 @@ def test_metrics_on_serving_is_bit_exact(tmp_path):
     }
 
 
+def test_serve_latency_counts_from_arrival():
+    """`serve.request_latency_s` runs from each request's arrival to its
+    retirement and `serve.queue_wait_s` from arrival to admission, both on
+    the engine's clock: a request that queues behind another counts its
+    wait."""
+    cfg = configs.get_smoke_config("lram-tiered")
+    params, state = transformer.init(jax.random.PRNGKey(0), cfg)
+    trace = synthetic_trace(np.random.default_rng(5), 4,
+                            vocab_size=cfg.vocab_size, max_prompt=6,
+                            max_gen=4, rate=200.0)
+    arrival = {r.id: r.arrival_s for r in trace}
+    obs.configure()
+    report = ServeEngine(params, state, cfg,
+                         EngineConfig(slots=1, max_len=11)).run(trace)
+    lat = obs.histogram("serve.request_latency_s")
+    wait = obs.histogram("serve.queue_wait_s")
+    assert lat.count == wait.count == len(trace)
+    assert lat.sum == pytest.approx(sum(r.finish_s - arrival[r.id]
+                                        for r in report.requests))
+    assert wait.sum == pytest.approx(sum(r.admit_s - arrival[r.id]
+                                         for r in report.requests))
+    assert wait.sum > 0  # one slot: later arrivals wait for it
+
+
 def test_train_step_hlo_identical_with_obs_armed():
     """The registry/tracer never enter traced code: the non-telemetry train
     step lowers to byte-identical HLO whether obs is armed or not."""

@@ -15,6 +15,7 @@ imports this file.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -64,9 +65,14 @@ def _shape(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, kernel: str):
+    """Compile `fn`; the program must hold a Mosaic kernel named `kernel`
+    (the `name=` of its `pallas_call`, which a profile's events carry)."""
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text(), "no Mosaic kernel"
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel"
+    assert re.search(rf"%{kernel}(\.\d+)? = .*tpu_custom_call", text), \
+        f"no kernel named {kernel}"
     return compiled
 
 
@@ -78,13 +84,13 @@ def test_dense_gather_compiles(one_chip, storage):
     w = _shape(one_chip, (TOKENS, TOP_K), jnp.float32)
     if storage == "fp32":
         _compile(lambda v, i, ww: gather_interp.gather_interp_pallas(v, i, ww),
-                 table, idx, w)
+                 table, idx, w, kernel="lram_gather")
     else:
         scale = _shape(one_chip, (rows,), jnp.float32)
         _compile(
             lambda q, s, i, ww: gather_interp.gather_interp_quant_pallas(
                 q, s, i, ww),
-            table, scale, idx, w,
+            table, scale, idx, w, kernel="lram_gather_quant",
         )
 
 
@@ -99,21 +105,22 @@ def test_tiered_gather_compiles(one_chip, storage):
         _compile(
             lambda c, i, s, ww: tiered_gather.tiered_gather_pallas(
                 c, i, s, ww, shard_rows=SHARD_ROWS),
-            cache, idx, slots, w,
+            cache, idx, slots, w, kernel="lram_tiered_gather",
         )
     else:
         scale = _shape(one_chip, (cache_rows,), jnp.float32)
         _compile(
             lambda c, sc, i, s, ww: tiered_gather.tiered_gather_quant_pallas(
                 c, sc, i, s, ww, shard_rows=SHARD_ROWS),
-            cache, scale, idx, slots, w,
+            cache, scale, idx, slots, w, kernel="lram_tiered_gather_quant",
         )
 
 
 def test_e8_query_kernel_compiles(one_chip):
     spec = indexing.choose_torus(LOG2_ROWS)
     q = _shape(one_chip, (TOKENS, 8), jnp.float32)
-    _compile(lambda qq: e8_lookup.lram_query_pallas(qq, spec, TOP_K), q)
+    _compile(lambda qq: e8_lookup.lram_query_pallas(qq, spec, TOP_K), q,
+             kernel="lram_e8_query")
 
 
 def test_pallas_train_step_compiles(one_chip, monkeypatch):
@@ -140,6 +147,10 @@ def test_pallas_train_step_compiles(one_chip, monkeypatch):
     step = build_train_step(cfg, optim.OptimConfig())
     compiled = step.lower(place(params), place(opt), place(mstate),
                           _shape(one_chip, (), jnp.float32), batch).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel keeps its name, and its forward its scope, in the step
+    assert re.search(r'%lram_gather(\.\d+)? = .*op_name="[^"]*lram\)?/gather/',
+                     text)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
