@@ -307,6 +307,23 @@ def neighbors_and_weights(
     Its phases run under the named scopes `decode` (nearest point and
     canonical frame), `distance` and `undo_isometry`.
     """
+    c, perm, sgn, w = canonical_weights(q)
+    cand = jnp.asarray(candidate_arrays()[0], dtype=q.dtype)
+    with jax.named_scope("undo_isometry"):
+        return undo_isometry(cand, c, perm, sgn), w
+
+
+def canonical_weights(
+    q: jax.Array,
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Nearest point, canonical isometry and the 232 candidates' weights.
+
+    Returns (c, perm, sgn, w): `c` = decode(q), `canonicalize`'s `perm`
+    and `sgn` of q - c, and w (..., 232), the kernel weight of each row of
+    `candidate_table()` from the canonical point.  The isometry keeps
+    distances, so that is the weight from q of the global point that
+    `undo_isometry` maps the row to.
+    """
     cand, cand_nsq = candidate_arrays()
     cand = jnp.asarray(cand, dtype=q.dtype)
     cand_nsq = jnp.asarray(cand_nsq, dtype=q.dtype)
@@ -321,15 +338,35 @@ def neighbors_and_weights(
             + cand_nsq
         )
         w = kernel_from_sq(d2)
-    with jax.named_scope("undo_isometry"):
-        # k[perm_j] = sgn_j * p_j + c[perm_j]
-        inv = jnp.argsort(perm, axis=-1, stable=True)
-        sp = sgn[..., None, :] * cand  # (..., 232, 8)
-        glob = jnp.take_along_axis(
-            sp, jnp.broadcast_to(inv[..., None, :], sp.shape), axis=-1
-        )
-        neighbors = c[..., None, :] + glob
-    return neighbors, w
+    return c, perm, sgn, w
+
+
+def undo_isometry(
+    points: jax.Array, c: jax.Array, perm: jax.Array, sgn: jax.Array
+) -> jax.Array:
+    """Canonical-frame points back to global lattice points.
+
+    `canonicalize` maps t to z with z_j = sgn_j * t[perm_j], so a
+    canonical point p is the global point c + p @ S, with S the signed
+    permutation matrix S[l, i] = sgn_l * [perm_l == i].
+
+    Args:
+      points: (n, 8) points shared by every query (the candidate table)
+        or (..., n, 8) points of each query.
+      c, perm, sgn: (..., 8) from `decode` and `canonicalize`.
+
+    Returns:
+      (..., n, 8) global points.
+
+    The product is exact: coordinates are small integers and every output
+    has one nonzero term, so `HIGHEST` only keeps a lower default matmul
+    precision from rounding the operands.
+    """
+    s = sgn[..., :, None] * jax.nn.one_hot(perm, DIM, dtype=sgn.dtype)
+    glob = jnp.einsum(
+        "...kl,...li->...ki", points, s, precision=jax.lax.Precision.HIGHEST
+    )
+    return c[..., None, :] + glob
 
 
 def brute_force_neighbors(q: np.ndarray, radius_sq: float = RADIUS_SQ):

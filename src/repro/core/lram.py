@@ -119,7 +119,9 @@ def indices_and_weights(
 
     Two equivalent selection strategies (tests assert identical results):
 
-    * no mesh (host/tests): jax.lax.top_k — fastest single-device.
+    * no mesh (host/tests): jax.lax.top_k — fastest single-device — over
+      the canonical frame's weights, and only the top_k selected candidates
+      are mapped back to global points.
     * under a mesh: top_k unrolled masked-argmax passes (mirroring the
       Pallas kernel).  XLA's sort-based top_k does not partition on
       non-sort dims and all-gathered the full 232-candidate tensor
@@ -128,20 +130,30 @@ def indices_and_weights(
       candidates are computed up front and selected by an exact integer
       one-hot reduction — no sorts, no gathers.
 
-    Besides `neighbors_and_weights`' scopes, the selection runs under the
-    named scope `top_k` and the lattice-point encoding under `encode`."""
+    Besides `neighbors_and_weights`' scopes (`decode`, `distance`,
+    `undo_isometry`), the selection runs under the named scope `top_k` and
+    the lattice-point encoding under `encode`."""
     from repro.distributed import context as _ctx
 
-    nbrs, w = lattice.neighbors_and_weights(q)  # (...,232,8), (...,232)
     if _ctx.get_mesh() is None:
         # host path: sort-based top_k is fastest on a single device
+        c, perm, sgn, w = lattice.canonical_weights(q)
+        cand = jnp.asarray(lattice.candidate_arrays()[0], dtype=q.dtype)
         with jax.named_scope("top_k"):
             w_top, sel = jax.lax.top_k(w, top_k)
-            nb_top = jnp.take_along_axis(
-                nbrs, sel[..., None].astype(jnp.int32), axis=-2
+            # the selected table rows (..., top_k, 8) as a one-hot product:
+            # exact, and faster on a TPU than a gather of 8-wide rows
+            cand_top = jnp.einsum(
+                "...kj,jl->...kl",
+                jax.nn.one_hot(sel, lattice.NUM_CANDIDATES, dtype=q.dtype),
+                cand,
+                precision=jax.lax.Precision.HIGHEST,
             )
+        with jax.named_scope("undo_isometry"):
+            nb_top = lattice.undo_isometry(cand_top, c, perm, sgn)
         with jax.named_scope("encode"):
             return indexing.encode_points(nb_top, spec), w_top
+    nbrs, w = lattice.neighbors_and_weights(q)  # (...,232,8), (...,232)
     with jax.named_scope("encode"):
         idx_all = indexing.encode_points(nbrs, spec)  # (..., 232) int32
     with jax.named_scope("top_k"):

@@ -1,12 +1,14 @@
 """Pin the lattice implementation to the paper's exact constants (§2.4-2.6)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypo import given, settings, st
 
-from repro.core import lattice
+from repro.core import indexing, lattice
 
 
 # ---------------------------------------------------------------------------
@@ -197,3 +199,139 @@ def test_kernel_function_values():
     assert float(lattice.kernel_from_sq(jnp.asarray(4.0))) == pytest.approx(
         0.5**4
     )
+
+
+# ---------------------------------------------------------------------------
+# Undoing the canonical isometry: the signed-permutation product against the
+# per-scalar gather it replaced
+# ---------------------------------------------------------------------------
+
+def _gather_neighbors(q):
+    """The former formulation, kept as the oracle: all 232 candidates put
+    back in the global frame by `argsort(perm)` and a per-scalar
+    `take_along_axis`."""
+    cand, cand_nsq = map(jnp.asarray, lattice.candidate_arrays())
+    c = lattice.decode(q)
+    z, perm, sgn = lattice.canonicalize(q - c)
+    d2 = jnp.sum(z * z, axis=-1, keepdims=True) - 2.0 * (z @ cand.T) + cand_nsq
+    w = lattice.kernel_from_sq(d2)
+    inv = jnp.argsort(perm, axis=-1, stable=True)
+    sp = sgn[..., None, :] * cand
+    glob = jnp.take_along_axis(
+        sp, jnp.broadcast_to(inv[..., None, :], sp.shape), axis=-1
+    )
+    return c[..., None, :] + glob, w
+
+
+def _gather_indices_and_weights(q, spec, top_k):
+    nbrs, w = _gather_neighbors(q)
+    w_top, sel = jax.lax.top_k(w, top_k)
+    nb_top = jnp.take_along_axis(nbrs, sel[..., None], axis=-2)
+    return indexing.encode_points(nb_top, spec), w_top
+
+
+def _queries(case: str) -> np.ndarray:
+    rng = np.random.default_rng(14)
+    if case.startswith("uniform-"):
+        scale = float(case.split("-")[1])
+        return rng.uniform(-scale, scale, size=(48, 3, 8)).astype(np.float32)
+    spec = indexing.choose_torus(16)
+    centres = indexing.decode_index(
+        rng.integers(0, spec.num_locations, size=96), spec
+    )
+    if case == "lattice-points":
+        pts = np.concatenate([centres, centres[:24] + lattice.shell_vectors()[
+            rng.integers(0, 2401, size=24)]])
+        return pts.astype(np.float32)
+    assert case == "ties-and-signed-zeros"
+    # offsets whose |t| repeat (ties in the canonical sort); the first rows
+    # sit at the origin, so their coordinates are +0.0 and -0.0 themselves
+    vals = np.array([0.0, -0.0, 0.25, -0.25, 0.5, -0.5, 0.75, -0.75, 1.0],
+                    dtype=np.float32)
+    t = vals[rng.integers(0, len(vals), size=(96, 8))]
+    t[8:16] = np.float32(0.5) * np.where(rng.random((8, 8)) < 0.5, -1, 1)
+    q = centres.astype(np.float32) + t
+    q[:16] = t[:16]
+    q[16] = np.float32(-0.0)
+    return q
+
+
+UNDO_CASES = ["uniform-1", "uniform-5", "uniform-50", "lattice-points",
+              "ties-and-signed-zeros"]
+
+
+@pytest.mark.parametrize("case", UNDO_CASES)
+def test_undo_isometry_matches_gather(case):
+    """All 232 neighbours are the gather's lattice points and the weights
+    its bits.  Neighbours compare as numbers: a zero coordinate may come
+    out as -0.0 in one and +0.0 in the other, the same lattice point."""
+    q = jnp.asarray(_queries(case))
+    nb, w = jax.jit(lattice.neighbors_and_weights)(q)
+    nb_ref, w_ref = jax.jit(_gather_neighbors)(q)
+    np.testing.assert_array_equal(np.asarray(nb), np.asarray(nb_ref))
+    np.testing.assert_array_equal(np.asarray(w).view(np.uint32),
+                                  np.asarray(w_ref).view(np.uint32))
+    spec = indexing.choose_torus(16)
+    np.testing.assert_array_equal(
+        np.asarray(indexing.encode_points(nb, spec)),
+        np.asarray(indexing.encode_points(nb_ref, spec)),
+    )
+
+
+@pytest.fixture(params=["host", "mesh"])
+def branch(request):
+    """`indices_and_weights` without a mesh (lax.top_k, then only the
+    selected candidates put back in the global frame) and under a
+    one-device mesh (all 232 put back, then masked-argmax passes)."""
+    from repro.distributed import context
+    from repro.launch.mesh import make_mesh
+
+    if request.param == "mesh":
+        context.set_mesh(make_mesh((1, 1), ("data", "model")))
+    yield request.param
+    context.set_mesh(None)
+
+
+@pytest.mark.parametrize("case", UNDO_CASES)
+def test_indices_and_weights_match_gather(case, branch):
+    from repro.core import lram
+    from repro.distributed import context
+
+    assert (context.get_mesh() is None) == (branch == "host")
+    spec = indexing.choose_torus(16)
+    q = jnp.asarray(_queries(case))
+    idx, w = jax.jit(lram.indices_and_weights, static_argnums=(1, 2))(
+        q, spec, lattice.DEFAULT_TOP_K)
+    idx_ref, w_ref = jax.jit(_gather_indices_and_weights,
+                             static_argnums=(1, 2))(
+        q, spec, lattice.DEFAULT_TOP_K)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(idx_ref))
+    np.testing.assert_array_equal(np.asarray(w).view(np.uint32),
+                                  np.asarray(w_ref).view(np.uint32))
+
+
+def test_query_has_no_scalar_gather_undoing_the_isometry():
+    """The train cell's query shape, compiled on the CPU: no gather runs
+    under `lram/query/undo_isometry`, and none reads an operand of 232 x 8
+    values a query (the former `take_along_axis` read 121,634,816 scalars
+    one index each)."""
+    from repro.core import lram
+
+    spec = indexing.choose_torus(20)
+    shape = (16, 128, 32, 8)
+
+    def query(q):
+        with jax.named_scope("lram"), jax.named_scope("query"):
+            return lram.indices_and_weights(q, spec, lattice.DEFAULT_TOP_K)
+
+    hlo = jax.jit(query).lower(
+        jax.ShapeDtypeStruct(shape, jnp.float32)).compile().as_text()
+    assert "lram/query/undo_isometry" in hlo
+    sizes = {m.group(1): int(np.prod([int(d) for d in m.group(2).split(",")
+                                      if d]))
+             for m in re.finditer(r"%(\S+) = \w+\[([\d,]*)\]", hlo)}
+    all_candidates = np.prod(shape[:-1]) * lattice.NUM_CANDIDATES * lattice.DIM
+    for ln in re.findall(r".* gather\(.*", hlo):
+        assert "lram/query/undo_isometry" not in ln, ln
+        operand = re.search(r" gather\(%([^,)]+)", ln).group(1)
+        assert sizes[operand] < all_candidates, ln
