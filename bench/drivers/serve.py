@@ -136,7 +136,7 @@ def reference_gaps(conf, reqs: list, length: int, *, controls=None):
     gap of the token that the reference computed so puts first at the same
     positions (a control, see `bench/calibrate.py`)."""
     harness, weights, _ = _imports()
-    ref = harness.load_module(_BENCH / "reference" / f"{conf['reference']}.py")
+    ref = harness.reference(conf["reference"])
     params, state = weights.make(conf, conf["weight_seed"])
     toks, tgt = pack(reqs, length)
     pad = (-len(reqs)) % REF_BATCH

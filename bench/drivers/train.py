@@ -100,7 +100,7 @@ def reference_run(conf, mix, seed: int, dtype=jnp.float32,
     3-step change norms, per leaf.  `batch_rows` keeps only the first rows
     of each batch (a fault, for the limits' upper readings)."""
     harness, weights, gen = _imports()
-    ref = harness.load_module(_BENCH / "reference" / f"{conf['reference']}.py")
+    ref = harness.reference(conf["reference"])
     vocab = conf["vocab_size"]
     params, state = weights.make(conf, seed, dtype=dtype)
     m = jax.tree.map(jnp.zeros_like, params)
@@ -110,7 +110,7 @@ def reference_run(conf, mix, seed: int, dtype=jnp.float32,
     cdf = gen.zipf_cdf(vocab, mix["zipf_a"])
     losses, grad_norms = [], None
     for i in range(CHECK_STEPS):
-        b = gen.mlm_batch(mix, vocab, seed, i, cdf)
+        b = gen.train_batch(mix, vocab, seed, i, cdf)
         if batch_rows is not None:
             b = {k: x[:batch_rows] for k, x in b.items()}
         params, state, m, v, loss, grads = step(
@@ -152,8 +152,8 @@ def first_steps(step, conf, mix, seed: int):
     cdf = gen.zipf_cdf(vocab, mix["zipf_a"])
 
     def feed(i):
-        return jax.tree.map(jnp.asarray, gen.mlm_batch(mix, vocab, seed, i,
-                                                       cdf))
+        return jax.tree.map(jnp.asarray, gen.train_batch(mix, vocab, seed, i,
+                                                         cdf))
 
     params, model_state = weights.make(conf, seed)
     opt_state = optim.adam_init(params)

@@ -3,51 +3,43 @@ configuration file.  Nothing here reads the program or a trace.
 
 Model FLOPs count the multiply-adds that the model's mathematics needs
 (2 FLOPs each), not what an implementation happens to run: padding,
-recomputation and the optimizer's elementwise work do not count.  The
-memory layer counts its two projections (w -> w, h*m -> w), the distance
-products to the 232 candidate lattice points of each head's query (paper
-§2.6, 8 x 232 multiply-adds), and the weighted sum of top_k rows of m values
-(paper Table 3).  A backward pass counts twice its forward.
+recomputation and the optimizer's elementwise work do not count.  What
+depends on the architecture (attention, MLPs or experts, the output head)
+is counted by the configuration's reference module
+(`bench/reference/<reference>.py`: `macs_per_token`,
+`attn_macs_per_context`).  The memory FFN, which every configuration
+shares, is counted here: its two projections (w -> w, h*m -> w), the
+distance products to the 232 candidate lattice points of each head's query
+(paper §2.6, 8 x 232 multiply-adds), and the weighted sum of top_k rows of
+m values (paper Table 3).  A backward pass counts twice its forward.
 """
 
 from __future__ import annotations
+
+import harness
 
 CANDIDATES = 232      # lattice points within sqrt(8) of the fundamental region
 LATTICE_DIM = 8
 
 
-def _layer_dense(cfg) -> int:
-    """Multiply-adds per token of one layer's projections, without the
-    attention products (which depend on the context length)."""
-    d, f = cfg["d_model"], cfg["d_ff"]
-    hd = cfg["num_heads"] * cfg["head_dim"]
-    kvd = cfg["num_kv_heads"] * cfg["head_dim"]
-    attn = d * hd + 2 * d * kvd + hd * d
-    mlp = (3 if cfg["act"] == "swiglu" else 2) * d * f
-    return attn + mlp
-
-
-def _memory_layer_dense(cfg) -> int:
+def memory_ffn_macs(cfg) -> int:
+    """Multiply-adds per token of the memory FFN: its projections and the
+    lookup."""
     d, lr = cfg["d_model"], cfg["lram"]
-    hd = cfg["num_heads"] * cfg["head_dim"]
-    kvd = cfg["num_kv_heads"] * cfg["head_dim"]
-    attn = d * hd + 2 * d * kvd + hd * d
     proj = d * d + lr["heads"] * lr["m"] * d
     lookup = lr["heads"] * (LATTICE_DIM * CANDIDATES + lr["top_k"] * lr["m"])
-    return attn + proj + lookup
+    return proj + lookup
 
 
 def per_token_macs(cfg) -> int:
     """Multiply-adds per token outside attention's context products."""
-    n = cfg["num_layers"]
-    return ((n - 1) * _layer_dense(cfg) + _memory_layer_dense(cfg)
-            + cfg["d_model"] * cfg["vocab_size"])
+    return (harness.reference(cfg["reference"]).macs_per_token(cfg)
+            + memory_ffn_macs(cfg))
 
 
 def _attn_macs_per_context(cfg) -> int:
-    """Multiply-adds per (query, key) pair over all layers: the score and
-    the weighted value."""
-    return 2 * cfg["num_layers"] * cfg["num_heads"] * cfg["head_dim"]
+    """Multiply-adds per (query, key) pair over all layers."""
+    return harness.reference(cfg["reference"]).attn_macs_per_context(cfg)
 
 
 def train_flops_per_token(cfg, seq_len: int) -> float:

@@ -4,13 +4,18 @@ check, the compile cache, the clock, the trace, and the result line.
 Nothing here knows a cell, a model or a traffic mix by name: a cell is
 `BENCHMARK.json`'s entry plus `bench/workloads/<cell>.json`, which names
 its driver (`bench/drivers/<driver>.py`); the configuration is
-`bench/configs/<config>.json`, the traffic `bench/traffic/<mix>.json`, and
-each per-layer metric a reader `bench/metrics/<metric>.py`.
+`bench/configs/<config>.json` with its reference module
+`bench/reference/<reference>.py` (the model's mathematics, parameter
+layout and model FLOPs), the traffic `bench/traffic/<mix>.json`, and each
+per-layer metric a reader `bench/metrics/<metric>.py`.  A configuration
+of a registered architecture states its cuts under `reduced`, and gets the
+memory FFN inserted where the architecture has none (`program_config`).
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import glob
 import importlib.util
 import json
@@ -88,6 +93,15 @@ def load_module(path: Path, name: str | None = None):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+@functools.lru_cache(maxsize=None)
+def reference(name: str):
+    """The plain reference module `bench/reference/<name>.py`, loaded once
+    a process: the model's mathematics, its parameter layout and its model
+    FLOPs (see `bench/reference/lram_transformer.py`)."""
+    return load_module(BENCH / "reference" / f"{name}.py",
+                       f"bench_reference_{name.replace('-', '_')}")
 
 
 def driver(workload: dict):
@@ -259,9 +273,45 @@ def log(**fields) -> None:
 
 # ------------------------------------------------------------ configuration
 
+# A configuration file states ModelConfig fields by their names, but for
+# `memory_layer` (`lram_layers`) and `act` (named as in ACTS); a field of
+# UNSTATED that the file leaves out is held to the value there: a dense
+# model of full attention.
+ACTS = {"gelu_tanh": "gelu", "swiglu": "swiglu"}
+UNSTATED = {"family": "dense", "num_experts": 0, "attention": "full",
+            "pkm_layers": (), "tie_embeddings": False}
+NOT_FIELDS = ("name", "lram")   # stated otherwise, or held apart
+
+
+def config_field(key: str, value):
+    """(ModelConfig field, value in the program's terms) of a file's key,
+    or None for a key that names no field."""
+    import dataclasses
+
+    program_path()
+    from repro.models.config import ModelConfig
+
+    if key == "memory_layer":
+        return "lram_layers", (value,)
+    if key == "act":
+        return "act", ACTS[value]
+    names = {f.name for f in dataclasses.fields(ModelConfig)}
+    if key not in names or key in NOT_FIELDS:
+        return None
+    return key, tuple(value) if isinstance(value, list) else value
+
+
 def program_config(conf: dict):
-    """The program's ModelConfig for a configuration file, held to every
-    size the file states (raises where they differ)."""
+    """The program's ModelConfig for a configuration file.
+
+    It is the registered arch (`arch`; its smoke configuration where the
+    file says `smoke`) with exactly the keys that the file's `reduced`
+    object names replaced by the file's values (each entry gives the
+    `published` value and `why`), the memory FFN inserted by
+    `configs.with_lram` at `memory_layer` where the arch has none, and the
+    table's placement swapped to `lram.placement`.  Every ModelConfig field
+    and memory key the file states is then held to the program's value,
+    and a difference raises."""
     import dataclasses
 
     program_path()
@@ -269,26 +319,30 @@ def program_config(conf: dict):
 
     get = configs.get_smoke_config if conf.get("smoke") else configs.get_config
     cfg = get(conf["arch"])
+    cuts = {}
+    for key, entry in conf.get("reduced", {}).items():
+        field = config_field(key, conf[key])
+        if field is None:
+            raise BenchError(f"{conf['name']}: `reduced` names {key!r}, "
+                             f"which is no field of the program's config")
+        published = config_field(key, entry["published"])[1]
+        if not conf.get("smoke") and getattr(cfg, field[0]) != published:
+            raise BenchError(f"{conf['name']}: {conf['arch']} registers "
+                             f"{key} = {getattr(cfg, field[0])!r}, the "
+                             f"file's `reduced` says {published!r}")
+        cuts[field[0]] = field[1]
+    cfg = dataclasses.replace(cfg, **cuts)
     lr = conf["lram"]
+    if cfg.lram is None:
+        cfg = configs.with_lram(cfg, lr["log2_locations"],
+                                conf["memory_layer"])
     if lr["placement"] != cfg.lram.interp_impl:
         cfg = configs.with_placement(cfg, lr["placement"])
-    acts = {"gelu_tanh": "gelu", "swiglu": "swiglu"}
-    want = {
-        "objective": conf["objective"], "num_layers": conf["num_layers"],
-        "d_model": conf["d_model"], "num_heads": conf["num_heads"],
-        "num_kv_heads": conf["num_kv_heads"], "head_dim": conf["head_dim"],
-        "d_ff": conf["d_ff"], "vocab_size": conf["vocab_size"],
-        "pos_scheme": conf["pos_scheme"], "norm": conf["norm"],
-        "act": acts[conf["act"]], "qkv_bias": conf["qkv_bias"],
-        "mlp_bias": conf["mlp_bias"], "dtype": conf["dtype"],
-        "lram_layers": (conf["memory_layer"],), "tie_embeddings": False,
-        "family": "dense", "pkm_layers": (), "num_experts": 0,
-        "attention": "full",
-    }
-    if conf["pos_scheme"] == "learned":
-        want["max_seq"] = conf["max_seq"]
-    if conf["pos_scheme"] == "rope":
-        want["rope_theta"] = conf["rope_theta"]
+    want = dict(UNSTATED)
+    for key, value in conf.items():
+        field = config_field(key, value)
+        if field:
+            want[field[0]] = field[1]
     got = {k: getattr(cfg, k) for k in want}
     got_lram = {"log2_locations": cfg.lram.log2_locations, "m": cfg.lram.m,
                 "heads": cfg.lram.heads, "top_k": cfg.lram.top_k,

@@ -32,6 +32,15 @@ Departures from the published description, each the configuration's own
 statement: GELU is the tanh approximation (`act: gelu_tanh`), norms take
 eps 1e-6, and attention softmax runs over all positions of a sequence
 (no padding mask: no traffic here pads a training sequence).
+
+A configuration file names its reference module (`reference`), and the
+benchmark takes from that module, besides the model's mathematics, what
+depends on its architecture: the parameter layout that `bench/weights.py`
+builds (`layout`) and the model FLOPs that `bench/flops.py` counts
+(`macs_per_token`, `attn_macs_per_context`).  This module's are those of a
+dense transformer with one memory layer; a module for another architecture
+may import this one and replace what differs (`layout(cfg, layer=...)`,
+`forward(..., attend=...)`, `train_step_fn(cfg, forward=...)`).
 """
 
 from __future__ import annotations
@@ -178,14 +187,10 @@ def rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def attention(cfg, p, x):
-    b, s, _ = x.shape
-    h, hd = cfg["num_heads"], cfg["head_dim"]
-    q = dense(cfg, p["wq"], x).reshape(b, s, h, hd)
-    k = dense(cfg, p["wk"], x).reshape(b, s, h, hd)
-    v = dense(cfg, p["wv"], x).reshape(b, s, h, hd)
-    if cfg["pos_scheme"] == "rope":
-        q, k = rope(q, cfg["rope_theta"]), rope(k, cfg["rope_theta"])
+def softmax_attention(cfg, q, k, v):
+    """Softmax attention of q over k and v, each (B, S, H, D), causal for
+    the `clm` objective; (B, S, H * D)."""
+    b, s, h, hd = q.shape
     scores = jnp.einsum("bshd,bthd->bhst", _op(cfg, q),
                         _op(cfg, k)) / np.sqrt(hd)
     if cfg["objective"] == "clm":
@@ -193,7 +198,19 @@ def attention(cfg, p, x):
         scores = jnp.where(causal, scores, -jnp.inf)
     out = jnp.einsum("bhst,bthd->bshd", _op(cfg, jax.nn.softmax(
         scores, -1)), _op(cfg, v))
-    return dense(cfg, p["wo"], out.reshape(b, s, h * hd))
+    return out.reshape(b, s, h * hd)
+
+
+def attention(cfg, p, x):
+    """Multi-head attention: keys and values have `num_heads` heads."""
+    b, s, _ = x.shape
+    h, hd = cfg["num_heads"], cfg["head_dim"]
+    q = dense(cfg, p["wq"], x).reshape(b, s, h, hd)
+    k = dense(cfg, p["wk"], x).reshape(b, s, h, hd)
+    v = dense(cfg, p["wv"], x).reshape(b, s, h, hd)
+    if cfg["pos_scheme"] == "rope":
+        q, k = rope(q, cfg["rope_theta"]), rope(k, cfg["rope_theta"])
+    return dense(cfg, p["wo"], softmax_attention(cfg, q, k, v))
 
 
 def memory(cfg, p, st, x, *, train):
@@ -238,8 +255,9 @@ def segments(cfg) -> list[tuple]:
     return out
 
 
-def forward(cfg, params, state, tokens, *, train=False):
-    """Logits (B, S, V) and the new batch-norm state."""
+def forward(cfg, params, state, tokens, *, train=False, attend=attention):
+    """Logits (B, S, V) and the new batch-norm state; `attend` is every
+    layer's attention."""
     x = params["embed"]["embedding"][tokens]
     if cfg["pos_scheme"] == "learned":
         x = x + params["pos_embed"][: tokens.shape[1]]
@@ -250,11 +268,10 @@ def forward(cfg, params, state, tokens, *, train=False):
         if seg[0] == "run":
             for j in range(seg[1]):
                 lp = jax.tree.map(lambda a, j=j: a[j], sp)
-                x = x + attention(cfg, lp["attn"], norm(cfg, lp["attn_norm"],
-                                                         x))
+                x = x + attend(cfg, lp["attn"], norm(cfg, lp["attn_norm"], x))
                 x = x + mlp(cfg, lp["mlp"], norm(cfg, lp["ffn_norm"], x))
         else:
-            x = x + attention(cfg, sp["attn"], norm(cfg, sp["attn_norm"], x))
+            x = x + attend(cfg, sp["attn"], norm(cfg, sp["attn_norm"], x))
             y, new_state[name] = memory(cfg, sp["memffn"], state[name],
                                         norm(cfg, sp["ffn_norm"], x),
                                         train=train)
@@ -264,7 +281,9 @@ def forward(cfg, params, state, tokens, *, train=False):
             new_state)
 
 
-def mlm_loss(cfg, params, state, batch, *, ignore=-100):
+def token_loss(cfg, params, state, batch, *, forward=forward, ignore=-100):
+    """Mean cross-entropy of the labels that are not `ignore` (MLM: the
+    masked positions; CLM: every next token)."""
     logits, new_state = forward(cfg, params, state, batch["tokens"],
                                 train=True)
     labels = batch["labels"]
@@ -304,14 +323,109 @@ def adam_step(opt, params, grads, count, m, v):
     return pick(0), pick(1), pick(2), grads
 
 
-def train_step_fn(cfg):
+def train_step_fn(cfg, forward=forward):
     """A jittable (params, state, m, v, count, batch) -> (params, state, m,
     v, loss, clipped grads)."""
     def step(params, state, m, v, count, batch):
         (loss, new_state), grads = jax.value_and_grad(
-            lambda p: mlm_loss(cfg, p, state, batch), has_aux=True)(params)
+            lambda p: token_loss(cfg, p, state, batch, forward=forward),
+            has_aux=True)(params)
         params, m, v, grads = adam_step(cfg["optimizer"], params, grads,
                                         count, m, v)
         return params, new_state, m, v, loss, grads
 
     return step
+
+
+# ------------------------------------------------------- layout and FLOPs
+
+def _norm_shapes(cfg) -> dict:
+    d = (cfg["d_model"],)
+    return {"scale": d, "bias": d} if cfg["norm"] == "layer" else {"scale": d}
+
+
+def _dense_shapes(i: int, o: int, bias: bool) -> dict:
+    return {"kernel": (i, o), "bias": (o,)} if bias else {"kernel": (i, o)}
+
+
+def attention_block_shapes(cfg) -> dict:
+    """A layer's attention and its two norms: what every layer has."""
+    d = cfg["d_model"]
+    hd = cfg["num_heads"] * cfg["head_dim"]
+    kvd = cfg["num_kv_heads"] * cfg["head_dim"]
+    qb = cfg["qkv_bias"]
+    attn = {"wq": _dense_shapes(d, hd, qb), "wk": _dense_shapes(d, kvd, qb),
+            "wv": _dense_shapes(d, kvd, qb), "wo": _dense_shapes(hd, d, False)}
+    return {"attn_norm": _norm_shapes(cfg), "attn": attn,
+            "ffn_norm": _norm_shapes(cfg)}
+
+
+def dense_layer_shapes(cfg) -> dict:
+    """A layer with a dense MLP."""
+    d, f, mb = cfg["d_model"], cfg["d_ff"], cfg["mlp_bias"]
+    if cfg["act"] == "swiglu":
+        mlp = {"wi_gate": _dense_shapes(d, f, mb),
+               "wi_up": _dense_shapes(d, f, mb), "wo": _dense_shapes(f, d, mb)}
+    else:
+        mlp = {"wi": _dense_shapes(d, f, mb), "wo": _dense_shapes(f, d, mb)}
+    return dict(attention_block_shapes(cfg), mlp=mlp)
+
+
+def layout(cfg, layer=dense_layer_shapes) -> tuple[dict, dict]:
+    """(params, state) as nested dicts of shape tuples, in the program's
+    layout: `layer(cfg)` is one layer outside the memory layer, stacked
+    over each run of them."""
+    d, v = cfg["d_model"], cfg["vocab_size"]
+    lr = cfg["lram"]
+    params: dict = {"embed": {"embedding": (v, d)},
+                    "final_norm": _norm_shapes(cfg)}
+    if cfg["pos_scheme"] == "learned":
+        params["pos_embed"] = (cfg["max_seq"], d)
+    params["lm_head"] = {"kernel": (d, v)}
+    segs, state = {}, {}
+    for si, seg in enumerate(segments(cfg)):
+        if seg[0] == "run":
+            segs[f"seg{si}"] = jax.tree.map(
+                lambda s, n=seg[1]: (n,) + s, layer(cfg),
+                is_leaf=lambda x: isinstance(x, tuple))
+        else:
+            q = 2 * 8  # real and imaginary parts of 8 complex coordinates
+            segs[f"seg{si}"] = dict(attention_block_shapes(cfg), memffn={
+                "wi": _dense_shapes(d, d, True),
+                "lram": {"values": (2 ** lr["log2_locations"], lr["m"]),
+                         "qnorm": {"scale": (q,), "bias": (q,)}},
+                "wo": _dense_shapes(lr["heads"] * lr["m"], d, True),
+            })
+            state[f"seg{si}"] = {"lram": {"qnorm": {"mean": (q,),
+                                                   "var": (q,)}}}
+    params["segments"] = segs
+    return params, state
+
+
+def attention_projection_macs(cfg) -> int:
+    """Multiply-adds per token of one layer's q, k, v and output
+    projections."""
+    d = cfg["d_model"]
+    hd = cfg["num_heads"] * cfg["head_dim"]
+    kvd = cfg["num_kv_heads"] * cfg["head_dim"]
+    return d * hd + 2 * d * kvd + hd * d
+
+
+def mlp_macs(cfg) -> int:
+    """Multiply-adds per token of one dense MLP."""
+    return (3 if cfg["act"] == "swiglu" else 2) * cfg["d_model"] * cfg["d_ff"]
+
+
+def macs_per_token(cfg) -> int:
+    """Multiply-adds per token outside the memory FFN and outside
+    attention's context products: every layer's attention projections,
+    the MLP of every layer but the memory layer, and the output head."""
+    n = cfg["num_layers"]
+    return (n * attention_projection_macs(cfg) + (n - 1) * mlp_macs(cfg)
+            + cfg["d_model"] * cfg["vocab_size"])
+
+
+def attn_macs_per_context(cfg) -> int:
+    """Multiply-adds per (query, key) pair over all layers: the score and
+    the weighted value of every query head."""
+    return 2 * cfg["num_layers"] * cfg["num_heads"] * cfg["head_dim"]

@@ -21,18 +21,46 @@ SMOKE_MODEL = {"d_model": 64, "num_heads": 4, "num_kv_heads": 4,
                "head_dim": 16, "d_ff": 128, "vocab_size": 256, "smoke": True}
 
 
+SMOKE_LRAM = {"log2_locations": 16, "torus_K": [8] * 8}
+
+
 def smoke_config(name: str) -> dict:
-    conf = copy.deepcopy(harness.load_json(BENCH / "configs" /
-                                           f"{name}.json"))
+    """The configuration file `name` at the smoke size."""
+    return smoke_size(harness.load_json(BENCH / "configs" / f"{name}.json"))
+
+
+def smoke_size(conf: dict) -> dict:
+    """A copy of the configuration `conf` at the smoke size."""
+    conf = copy.deepcopy(conf)
+    if not conf["arch"].startswith("lram-"):
+        return _inserted_smoke_config(conf)
     conf.update(SMOKE_MODEL)
-    conf["lram"].update({"log2_locations": 16, "heads": 4,
-                         "torus_K": [8] * 8})
+    conf["lram"].update(SMOKE_LRAM, heads=4)
     if conf["arch"].startswith("lram-bert"):
         conf.update({"num_layers": 3, "memory_layer": 1, "max_seq": 64,
                      "head_dim": 8})
     else:
         conf.update({"num_layers": 2, "memory_layer": 1})
         conf["lram"]["tiered"].update({"shard_rows": 2048, "cache_slots": 8})
+    return conf
+
+
+def _inserted_smoke_config(conf: dict) -> dict:
+    """A registered architecture with the memory FFN inserted: every field
+    the file states takes the arch's smoke value (its cuts too), the
+    memory layer is the second, the table has 2^16 rows, and the control
+    is bfloat16, the step below the smoke size's float32."""
+    from repro import configs
+
+    small = configs.get_smoke_config(conf["arch"])
+    for key in conf:
+        field = harness.config_field(key, conf[key])
+        if field and field[0] != "lram_layers":
+            value = getattr(small, field[0])
+            conf[key] = list(value) if isinstance(value, tuple) else value
+    conf["act"] = {v: k for k, v in harness.ACTS.items()}[small.act]
+    conf.update(smoke=True, memory_layer=1, control_dtype="bfloat16")
+    conf["lram"].update(SMOKE_LRAM, heads=small.d_model // 16)
     return conf
 
 
@@ -64,9 +92,9 @@ def smoke_cell(name: str) -> dict:
     """The cell `name` with its configuration, traffic and engine cut to
     the smoke size."""
     cell = full_cell(name)
-    cell["config"] = smoke_config(cell["config"]["name"])
+    cell["config"] = smoke_size(cell["config"])
     mix = cell["traffic"]
-    if mix["kind"] == "mlm":
+    if mix["kind"] in ("mlm", "clm"):
         mix.update({"batch": 4, "seq_len": 32})
     else:
         mix["prompt"].update({"median": 8, "min": 4, "max": 40})

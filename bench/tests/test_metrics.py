@@ -48,6 +48,14 @@ def test_forward_flops_of_a_request():
                                       for p in range(14)))
 
 
+def test_train_cell_counts_are_unchanged():
+    """The train cell's model FLOPs and lookup bytes, as the reference
+    module counts them now and the harness counted them before."""
+    assert flops.train_flops_per_token(BERT, 128) == 206_155_776
+    assert flops.lookup_bytes(BERT, 2048, backward=True) == 1_644_167_168
+    assert flops.per_token_macs(TIERED) == 30_951_424
+
+
 def test_lookup_bytes():
     fwd = flops.lookup_bytes(BERT, 2048, backward=False)
     pairs = 2048 * 32 * 32

@@ -2,6 +2,7 @@
 window, and the same work for every seed."""
 
 import numpy as np
+import pytest
 import smoke  # noqa: F401  (puts bench/ on the import path)
 from traffic import generate as gen
 
@@ -80,3 +81,47 @@ def test_check_mix_refuses_bad_files():
     except ValueError:
         return
     raise AssertionError("an unknown arrival process was accepted")
+
+
+CLM = {"kind": "clm", "batch": 8, "seq_len": 64, "zipf_a": 1.2}
+
+
+def test_clm_batches():
+    a = gen.train_batch(CLM, 30000, BIG_SEED, 3)
+    b = gen.clm_batch(CLM, 30000, BIG_SEED, 3)
+    for k in ("tokens", "labels"):
+        assert a[k].shape == (CLM["batch"], CLM["seq_len"])
+        assert a[k].dtype == np.int32
+        np.testing.assert_array_equal(a[k], b[k])
+    # each label is the next token: no padding, nothing ignored
+    np.testing.assert_array_equal(a["labels"][:, :-1], a["tokens"][:, 1:])
+    assert np.all(a["labels"] >= 0)
+    for ids in (a["tokens"], a["labels"]):
+        assert ids.min() >= 0 and ids.max() < 30000 - 1
+    for seed, step in ((7, 3), (BIG_SEED, 4)):
+        c = gen.train_batch(CLM, 30000, seed, step)
+        assert not np.array_equal(a["tokens"], c["tokens"])
+        assert c["tokens"].shape == a["tokens"].shape
+        assert c["labels"].size == a["labels"].size
+
+
+def test_mlm_batch_is_unchanged():
+    """The train cell's batches, byte for byte as before `clm` came."""
+    import hashlib
+
+    b = gen.train_batch(MLM, 30000, BIG_SEED, 3)
+    digest = hashlib.sha256(b["tokens"].tobytes()
+                            + b["labels"].tobytes()).hexdigest()
+    assert digest == ("ba5e6c349d1baf5b89b2878e6404b500"
+                      "b037da552870e9fa535ce3d56b378a82")
+
+
+@pytest.mark.parametrize("bad", [
+    dict(CLM, seq_len=0), dict(CLM, batch=2.5), dict(CLM, zipf_a=-1.0),
+    dict(CLM, zipf_a=float("nan")), dict(CLM, mask_prob=0.15),
+    {k: v for k, v in CLM.items() if k != "zipf_a"},
+    dict(MLM, mask_prob=1.5), dict(CLM, kind="prefix-lm")])
+def test_check_mix_refuses_bad_training_mixes(bad):
+    gen.check_mix(CLM)
+    with pytest.raises((ValueError, KeyError)):
+        gen.check_mix(bad)

@@ -1,6 +1,6 @@
 """The one generator behind every traffic file in this directory.
 
-A traffic file (`<mix>.json`) is data: its `kind` picks one of the two
+A traffic file (`<mix>.json`) is data: its `kind` picks one of the
 generators below, and the rest of the file are its parameters.  Everything
 is a pure function of the file, `--seed` and the window length, so the same
 seed gives the same inputs.
@@ -16,6 +16,10 @@ between runs is the system's and not the draw's.
   vocabulary's last id), 10% by a random id, 10% kept (BERT's recipe).
   Copied from the program's `repro.data.synthetic`, without its planted
   facts.
+* `clm`: causal-LM training batches: `batch` rows of `seq_len + 1` ids
+  from the same stream; the first `seq_len` are the tokens and the last
+  `seq_len` the labels (each position's next token), with no padding and
+  nothing ignored.
 * `requests`: serving requests.  `arrival: "poisson"` spaces `rate`
   requests per second with exponential gaps over the window;
   `arrival: "batch"` queues `count` requests at t = 0.  Prompt and output
@@ -81,6 +85,28 @@ def mlm_batch(mix: dict, vocab: int, seed: int, step: int,
     return {"tokens": tokens, "labels": labels}
 
 
+def clm_batch(mix: dict, vocab: int, seed: int, step: int,
+              cdf: np.ndarray | None = None) -> dict:
+    """{"tokens", "labels"} int32 (batch, seq_len) for one step: labels
+    are the tokens shifted by one."""
+    rng = _rng(seed, 1, step)
+    cdf = zipf_cdf(vocab, mix["zipf_a"]) if cdf is None else cdf
+    ids = np.searchsorted(cdf, rng.random((mix["batch"], mix["seq_len"] + 1))
+                          ).astype(np.int32)
+    return {"tokens": ids[:, :-1], "labels": ids[:, 1:]}
+
+
+TRAIN_BATCHES = {"mlm": mlm_batch, "clm": clm_batch}
+TRAIN_KEYS = {"mlm": {"batch", "seq_len", "zipf_a", "mask_prob"},
+              "clm": {"batch", "seq_len", "zipf_a"}}
+
+
+def train_batch(mix: dict, vocab: int, seed: int, step: int,
+                cdf: np.ndarray | None = None) -> dict:
+    """Step `step`'s training batch of a training mix, by its `kind`."""
+    return TRAIN_BATCHES[mix["kind"]](mix, vocab, seed, step, cdf)
+
+
 # ------------------------------------------------------------------- serving
 
 @dataclasses.dataclass
@@ -128,5 +154,17 @@ def check_mix(mix: dict) -> None:
             raise ValueError(f"unknown arrival {mix['arrival']!r}")
         if mix["arrival"] == "poisson" and not math.isfinite(mix["rate"]):
             raise ValueError("rate must be finite")
-    elif mix["kind"] != "mlm":
+    elif mix["kind"] in TRAIN_KEYS:
+        keys = TRAIN_KEYS[mix["kind"]]
+        if set(mix) - {"kind", "about"} != keys:
+            raise ValueError(f"a {mix['kind']} mix has the keys "
+                             f"{sorted(keys)}, not {sorted(mix)}")
+        for k in ("batch", "seq_len"):
+            if not isinstance(mix[k], int) or mix[k] < 1:
+                raise ValueError(f"{k} must be a whole number >= 1")
+        if not (math.isfinite(mix["zipf_a"]) and mix["zipf_a"] > 0):
+            raise ValueError("zipf_a must be finite and > 0")
+        if "mask_prob" in mix and not 0 < mix["mask_prob"] <= 1:
+            raise ValueError("mask_prob must be in (0, 1]")
+    else:
         raise ValueError(f"unknown traffic kind {mix['kind']!r}")

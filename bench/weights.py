@@ -1,10 +1,14 @@
 """Seeded random weights for a configuration file, made on the device in
 one jitted call, in the program's parameter layout.
 
-The layout is derived here from the configuration's sizes, not read from
-the program; `check_layout` holds the program's own `init` shapes to it,
-so a run fails where the two part.  The plain reference builds its weights
-with the same call, so it takes nothing the program has made.
+The layout is the configuration's reference module's
+(`bench/reference/<reference>.py`, `layout`), derived from the
+configuration's sizes and not read from the program; `check_layout` holds
+the program's own `init` shapes to it, so a run fails where the two part.
+Every leaf is initialised by its name or, for any other weight of rank 2
+or more, by fan-in over its input axis (`shape[-2]`), so a new
+architecture's leaves need no code here.  The plain reference builds its
+weights with the same call, so it takes nothing the program has made.
 """
 
 from __future__ import annotations
@@ -14,6 +18,8 @@ import zlib
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import harness
 
 
 def prng_key(seed: int) -> jax.Array:
@@ -25,74 +31,9 @@ def prng_key(seed: int) -> jax.Array:
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def _norm(cfg) -> dict:
-    d = (cfg["d_model"],)
-    return {"scale": d, "bias": d} if cfg["norm"] == "layer" else {"scale": d}
-
-
-def _dense(i: int, o: int, bias: bool) -> dict:
-    return {"kernel": (i, o), "bias": (o,)} if bias else {"kernel": (i, o)}
-
-
-def _layer(cfg) -> dict:
-    d, f = cfg["d_model"], cfg["d_ff"]
-    hd = cfg["num_heads"] * cfg["head_dim"]
-    kvd = cfg["num_kv_heads"] * cfg["head_dim"]
-    qb, mb = cfg["qkv_bias"], cfg["mlp_bias"]
-    attn = {"wq": _dense(d, hd, qb), "wk": _dense(d, kvd, qb),
-            "wv": _dense(d, kvd, qb), "wo": _dense(hd, d, False)}
-    if cfg["act"] == "swiglu":
-        mlp = {"wi_gate": _dense(d, f, mb), "wi_up": _dense(d, f, mb),
-               "wo": _dense(f, d, mb)}
-    else:
-        mlp = {"wi": _dense(d, f, mb), "wo": _dense(f, d, mb)}
-    return {"attn_norm": _norm(cfg), "attn": attn, "ffn_norm": _norm(cfg),
-            "mlp": mlp}
-
-
-def segments(cfg) -> list[tuple]:
-    """[("run", first_layer, count) | ("memory", layer)] in layer order."""
-    out, start = [], 0
-    mem = cfg["memory_layer"]
-    for i in range(cfg["num_layers"] + 1):
-        if i == mem or i == cfg["num_layers"]:
-            if i > start:
-                out.append(("run", start, i - start))
-            if i == mem:
-                out.append(("memory", i))
-            start = i + 1
-    return out
-
-
 def layout(cfg) -> tuple[dict, dict]:
     """(params, state) as nested dicts of shape tuples."""
-    d, v = cfg["d_model"], cfg["vocab_size"]
-    lr = cfg["lram"]
-    params: dict = {"embed": {"embedding": (v, d)}, "final_norm": _norm(cfg)}
-    if cfg["pos_scheme"] == "learned":
-        params["pos_embed"] = (cfg["max_seq"], d)
-    params["lm_head"] = {"kernel": (d, v)}
-    segs, state = {}, {}
-    for si, seg in enumerate(segments(cfg)):
-        if seg[0] == "run":
-            segs[f"seg{si}"] = jax.tree.map(
-                lambda s, n=seg[2]: (n,) + s, _layer(cfg),
-                is_leaf=lambda x: isinstance(x, tuple))
-        else:
-            q = 2 * 8  # real and imaginary parts of 8 complex coordinates
-            layer = _layer(cfg)
-            del layer["mlp"]
-            layer["memffn"] = {
-                "wi": _dense(d, d, True),
-                "lram": {"values": (2 ** lr["log2_locations"], lr["m"]),
-                         "qnorm": {"scale": (q,), "bias": (q,)}},
-                "wo": _dense(lr["heads"] * lr["m"], d, True),
-            }
-            segs[f"seg{si}"] = layer
-            state[f"seg{si}"] = {"lram": {"qnorm": {"mean": (q,),
-                                                   "var": (q,)}}}
-    params["segments"] = segs
-    return params, state
+    return harness.reference(cfg["reference"]).layout(cfg)
 
 
 def _leaf_init(cfg, path: str, shape: tuple, key) -> jax.Array:
@@ -104,13 +45,13 @@ def _leaf_init(cfg, path: str, shape: tuple, key) -> jax.Array:
         return cfg["lram"]["value_init_std"] * normal
     if leaf == "pos_embed":
         return 0.02 * normal
-    if leaf == "kernel":
-        return normal / np.sqrt(shape[-2])
     if leaf == "scale":
         return 1.0 + 0.1 * normal
     if leaf == "bias":
         is_norm = "norm" in path.rsplit("/", 2)[-2]
         return (0.1 if is_norm else 0.02) * normal
+    if len(shape) >= 2:  # a kernel, a router, stacked expert weights
+        return normal / np.sqrt(shape[-2])
     raise ValueError(f"no initializer for {path}")
 
 
