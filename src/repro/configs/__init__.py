@@ -31,6 +31,7 @@ ARCHS = (
     "mamba2-1.3b",
     "whisper-small",
     "qwen2-vl-72b",
+    "moonlight-16b-a3b",
 )
 
 PAPER_MODELS = (
@@ -56,6 +57,7 @@ _MODULES = {
     "mamba2-1.3b": "mamba2_1_3b",
     "whisper-small": "whisper_small",
     "qwen2-vl-72b": "qwen2_vl_72b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "lram-bert-baseline": "lram_bert",
     "lram-bert-pkm": "lram_bert",
     "lram-bert-small": "lram_bert",

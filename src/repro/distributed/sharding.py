@@ -50,8 +50,8 @@ def _rules(ax: MeshAxes) -> list[tuple[str, tuple]]:
         (r"(attn|cross)/wo/kernel$", (T, F)),
         (r"(attn|cross)/w[qkv]/bias$", (T,)),
         # dense mlp
-        (r"mlp/wi(_gate|_up)?/kernel$", (F, T)),
-        (r"mlp/wo/kernel$", (T, F)),
+        (r"(mlp|moe/shared)/wi(_gate|_up)?/kernel$", (F, T)),
+        (r"(mlp|moe/shared)/wo/kernel$", (T, F)),
         (r"mlp/w[io].*?/bias$", (None,)),
         # MoE: experts on TP axis (expert parallelism) when E divides the
         # axis; otherwise Megatron-style TP *within* each expert (hidden dim

@@ -359,6 +359,11 @@ def _train(args, cfg, dcfg, opt_cfg, mesh):
                 "grad_norm": round(float(metrics["grad_norm"]), 3),
                 "sec": round(dt, 3),
             }
+            for name in ("routed_copies", "max_expert_load"):
+                # the expert layers' counters, returned by the step
+                if f"moe.{name}" in metrics:
+                    rec[name] = int(metrics[f"moe.{name}"])
+                    obs.gauge(f"moe.{name}").set(rec[name])
             if stores:
                 rec["cache_hit"] = round(
                     float(np.mean([s.hit_rate() for _, s in stores])), 4

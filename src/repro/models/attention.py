@@ -1,4 +1,5 @@
-"""Attention: GQA, RoPE / M-RoPE, sliding window, flash-style chunking.
+"""Attention: GQA, latent attention (MLA), RoPE / M-RoPE, sliding window,
+flash-style chunking.
 
 Three execution paths, all numerically identical (tested against each other):
 
@@ -126,7 +127,7 @@ def dense_attention(
     window: Optional[int] = None,
     q_offset: int = 0,
 ) -> jax.Array:
-    """q: (B,S,H,D), k/v: (B,T,Kh,D) -> (B,S,H,D)."""
+    """q: (B,S,H,D), k: (B,T,Kh,D), v: (B,T,Kh,Dv) -> (B,S,H,Dv)."""
     b, s, h, d = q.shape
     kh = k.shape[2]
     qg = q.reshape(b, s, kh, h // kh, d) * (d**-0.5)
@@ -136,7 +137,7 @@ def dense_attention(
     scores = jnp.where(jnp.asarray(mask), scores, _NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
     out = _attend(w, v)
-    return out.reshape(b, s, h, d).astype(q.dtype)
+    return out.reshape(b, s, h, v.shape[-1]).astype(q.dtype)
 
 
 def chunked_attention(
@@ -152,17 +153,19 @@ def chunked_attention(
     """Flash-style streaming-softmax attention; O(chunk^2) peak memory.
 
     Scan over query chunks; inside, scan over KV chunks carrying
-    (running_max, denominator, weighted accumulator).
+    (running_max, denominator, weighted accumulator).  The value width Dv
+    may differ from the query/key width D.
     """
     b, s, h, d = q.shape
     t = k.shape[1]
     kh = k.shape[2]
+    dv = v.shape[-1]
     assert s % q_chunk == 0 and t % kv_chunk == 0, (s, t, q_chunk, kv_chunk)
     nq, nk = s // q_chunk, t // kv_chunk
     qg = (q.reshape(b, s, kh, h // kh, d) * (d**-0.5))
     qg = qg.reshape(b, nq, q_chunk, kh, h // kh, d)
     kc = k.reshape(b, nk, kv_chunk, kh, d)
-    vc = v.reshape(b, nk, kv_chunk, kh, d)
+    vc = v.reshape(b, nk, kv_chunk, kh, dv)
 
     def q_step(_, qi_blk):
         qi, q_blk = qi_blk  # q_blk: (B, q_chunk, Kh, G, D)
@@ -199,7 +202,7 @@ def chunked_attention(
 
         m0 = jnp.full((b, kh, h // kh, q_chunk), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, kh, h // kh, q_chunk), jnp.float32)
-        a0 = jnp.zeros((b, kh, h // kh, q_chunk, d), jnp.float32)
+        a0 = jnp.zeros((b, kh, h // kh, q_chunk, dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(
             kv_step,
             (m0, l0, a0),
@@ -213,7 +216,7 @@ def chunked_attention(
     )
     # outs: (nq, B, Kh, G, qc, D) -> (B, S, H, D)
     out = jnp.moveaxis(outs, 0, 1)  # (B, nq, Kh, G, qc, D)
-    out = jnp.transpose(out, (0, 1, 4, 2, 3, 5)).reshape(b, s, h, d)
+    out = jnp.transpose(out, (0, 1, 4, 2, 3, 5)).reshape(b, s, h, dv)
     return out.astype(q.dtype)
 
 
@@ -259,6 +262,8 @@ def decode_attention(
 # ---------------------------------------------------------------------------
 
 def attn_init(key, cfg: ModelConfig, *, dtype=jnp.float32):
+    if cfg.attention == "mla":
+        return mla_init(key, cfg, dtype=dtype)
     kq, kk, kv, ko = jax.random.split(key, 4)
     h, khd, d = cfg.num_heads, cfg.num_kv_heads, cfg.d_model
     hd = cfg.head_dim
@@ -294,23 +299,85 @@ def attn_apply(
     cross_kv: Optional[tuple[jax.Array, jax.Array]] = None,
 ):
     """Full-sequence attention (train / prefill compute). x: (B, S, d)."""
+    if cfg.attention == "mla":
+        assert cross_kv is None, "latent attention has no cross-attention"
+        return mla_apply(params, x, cfg, positions=positions, causal=causal)
     b, s, _ = x.shape
     q, k, v = _project_qkv(params, x, cfg, positions)
     if cross_kv is not None:
         k, v = cross_kv
     window = cfg.window if cfg.attention == "swa" else None
+    out = _attention(q, k, v, cfg, causal=causal, window=window,
+                     chunkable=cross_kv is None)
+    out = out.reshape(b, s, cfg.num_heads * cfg.head_dim)
+    y = nn.dense(params["wo"], out)
+    return y, (k, v)
+
+
+def _attention(q, k, v, cfg: ModelConfig, *, causal, window=None,
+               chunkable=True):
+    """The dense or the chunked path, as `attn_impl` and the length ask."""
+    s = q.shape[1]
     use_chunked = cfg.attn_impl == "chunked" or (
-        cfg.attn_impl == "auto" and s > cfg.attn_chunk and cross_kv is None
+        cfg.attn_impl == "auto" and s > cfg.attn_chunk and chunkable
     )
     if use_chunked and s % cfg.attn_chunk == 0:
-        out = chunked_attention(
+        return chunked_attention(
             q, k, v, causal=causal, window=window,
             q_chunk=cfg.attn_chunk, kv_chunk=cfg.attn_chunk,
         )
-    else:
-        out = dense_attention(q, k, v, causal=causal, window=window)
-    out = out.reshape(b, s, cfg.num_heads * cfg.head_dim)
-    y = nn.dense(params["wo"], out)
+    return dense_attention(q, k, v, causal=causal, window=window)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA, DeepSeek-V2 §2.1), without query LoRA
+# ---------------------------------------------------------------------------
+
+def mla_init(key, cfg: ModelConfig, *, dtype=jnp.float32):
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {
+        "wq": nn.dense_init(kq, d, h * (nope + rope), use_bias=False,
+                            dtype=dtype),
+        "wkv_a": nn.dense_init(ka, d, r + rope, use_bias=False, dtype=dtype),
+        "kv_norm": nn.rmsnorm_init(r, dtype=dtype),
+        "wkv_b": nn.dense_init(kb, r, h * (nope + dv), use_bias=False,
+                               dtype=dtype),
+        "wo": nn.dense_init(ko, h * dv, d, use_bias=False, dtype=dtype),
+    }
+
+
+def mla_apply(params, x: jax.Array, cfg: ModelConfig, *, positions,
+              causal: bool = True):
+    """Full-sequence latent attention. x: (B, S, d) -> (y, (k, v)).
+
+    q = x W_q, split into q_nope and q_rope; [c_kv | k_rope] = x W_kv_a;
+    [k_nope | v] = RMSNorm(c_kv) W_kv_b; RoPE on q_rope and on the single
+    k_rope, which every head shares; scores (q_nope.k_nope + q_rope.k_rope)
+    / sqrt(nope + rope).  Keys are built per head, so the attention itself
+    is the plain multi-head path with a value width of its own."""
+    b, s, _ = x.shape
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    with jax.named_scope("mla"):
+        with jax.named_scope("q"):
+            q = nn.dense(params["wq"], x).reshape(b, s, h, nope + rope)
+            q_rope = apply_rope(q[..., nope:], positions, cfg.rope_theta)
+            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        with jax.named_scope("kv_latent"):
+            a = nn.dense(params["wkv_a"], x)
+            c_kv = nn.rmsnorm(params["kv_norm"], a[..., :r],
+                              eps=cfg.rms_norm_eps).astype(x.dtype)
+            k_rope = apply_rope(a[..., None, r:], positions, cfg.rope_theta)
+            kv = nn.dense(params["wkv_b"], c_kv).reshape(b, s, h, nope + dv)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_rope, (b, s, h, rope))], axis=-1)
+            v = kv[..., nope:]
+        with jax.named_scope("scores"):
+            out = _attention(q, k, v, cfg, causal=causal)
+        y = nn.dense(params["wo"], out.reshape(b, s, h * dv))
     return y, (k, v)
 
 
@@ -333,6 +400,8 @@ def attn_decode(
 
     Returns (y, new_k_cache, new_v_cache). For SWA the cache is a ring
     buffer of size `cfg.window`."""
+    if cfg.attention == "mla":
+        raise NotImplementedError("latent attention has no decode cache")
     b = x.shape[0]
     t = k_cache.shape[1]
     per_slot = jnp.ndim(pos) == 1

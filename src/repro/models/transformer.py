@@ -2,9 +2,11 @@
 
 The stack is compiled as a sequence of *segments*:
 
-  * ("run", n)       — n identical layers executed under jax.lax.scan with
+  * ("run", n, ffn)  — n identical layers executed under jax.lax.scan with
                        stacked params (compile time O(1) in depth — essential
-                       for 80-layer models x 80 dry-run compiles),
+                       for 80-layer models x 80 dry-run compiles); ffn is
+                       "mlp" or "moe", so a model's leading dense layers
+                       (`first_k_dense_replace`) are a run of their own,
   * ("memory", kind) — a single un-scanned layer whose FFN is replaced by the
                        paper's LRAM block (or the PKM baseline). Un-scanned
                        because it carries batchnorm state and its own shapes.
@@ -41,7 +43,8 @@ IGNORE = -100
 # ---------------------------------------------------------------------------
 
 def layer_plan(cfg: ModelConfig) -> list[tuple]:
-    """[("run", count) | ("memory", layer_idx, kind)] covering all layers."""
+    """[("run", count, ffn) | ("memory", layer_idx, kind)] covering all
+    layers; ffn is "moe" for an expert layer, else "mlp"."""
     special = {i: "lram" for i in cfg.lram_layers}
     special.update({i: "pkm" for i in cfg.pkm_layers})
     if cfg.family == "hybrid":
@@ -49,17 +52,14 @@ def layer_plan(cfg: ModelConfig) -> list[tuple]:
         assert cfg.num_layers % cfg.hybrid_pattern == 0
         return [("hybrid", cfg.num_layers // cfg.hybrid_pattern)]
     plan: list[tuple] = []
-    run = 0
     for i in range(cfg.num_layers):
+        ffn = "moe" if cfg.moe_layer(i) else "mlp"
         if i in special:
-            if run:
-                plan.append(("run", run))
-                run = 0
             plan.append(("memory", i, special[i]))
+        elif plan and plan[-1][0] == "run" and plan[-1][2] == ffn:
+            plan[-1] = ("run", plan[-1][1] + 1, ffn)
         else:
-            run += 1
-    if run:
-        plan.append(("run", run))
+            plan.append(("run", 1, ffn))
     return plan
 
 
@@ -76,16 +76,18 @@ def _norm_init(cfg, dtype):
 def _norm(cfg, params, x):
     if cfg.norm == "layer":
         return nn.layernorm(params, x)
-    return nn.rmsnorm(params, x)
+    return nn.rmsnorm(params, x, eps=cfg.rms_norm_eps)
 
 
-def _layer_init(key, cfg: ModelConfig, *, dtype, cross: bool = False):
+def _layer_init(key, cfg: ModelConfig, *, dtype, cross: bool = False,
+                ffn: str = "mlp"):
+    """(params, state) of one layer; only an expert layer has state."""
     ks = jax.random.split(key, 6)
     if cfg.family == "ssm":
         return {
             "norm": _norm_init(cfg, dtype),
             "mamba": mamba2.mamba_init(ks[0], cfg, dtype=dtype),
-        }
+        }, {}
     p = {
         "attn_norm": _norm_init(cfg, dtype),
         "attn": attention.attn_init(ks[0], cfg, dtype=dtype),
@@ -94,20 +96,22 @@ def _layer_init(key, cfg: ModelConfig, *, dtype, cross: bool = False):
     if cross:
         p["cross_norm"] = _norm_init(cfg, dtype)
         p["cross"] = attention.attn_init(ks[1], cfg, dtype=dtype)
-    if cfg.num_experts > 0:
-        p["moe"] = moe.moe_init(ks[2], cfg, dtype=dtype)
-    else:
-        p["mlp"] = mlp.mlp_init(ks[2], cfg, dtype=dtype)
-    return p
+    if ffn == "moe":
+        p["moe"], state = moe.moe_init(ks[2], cfg, dtype=dtype)
+        return p, ({"moe": state} if state else {})
+    p["mlp"] = mlp.mlp_init(ks[2], cfg, dtype=dtype)
+    return p, {}
 
 
 def _layer_full(lp, x, cfg: ModelConfig, positions, *, causal,
-                enc_out=None):
-    """Full-sequence layer. Returns (x, kv, aux)."""
+                enc_out=None, st=None, train=False):
+    """Full-sequence layer. Returns (x, kv, aux, new_st, counters); an
+    expert layer's counters are `moe.moe_apply`'s, a dense layer's None."""
     aux = jnp.zeros((), jnp.float32)
+    st = {} if st is None else st
     if cfg.family == "ssm":
         x = x + mamba2.mamba_apply(lp["mamba"], _norm(cfg, lp["norm"], x), cfg)
-        return x, None, aux
+        return x, None, aux, st, None
     h, kv = attention.attn_apply(
         lp["attn"], _norm(cfg, lp["attn_norm"], x), cfg,
         positions=positions, causal=causal,
@@ -121,14 +125,19 @@ def _layer_full(lp, x, cfg: ModelConfig, positions, *, causal,
         )
         x = x + h
     y = _norm(cfg, lp["ffn_norm"], x)
-    if cfg.num_experts > 0:
-        y, aux = moe.moe_apply(lp["moe"], y, cfg)
+    counters = None
+    if "moe" in lp:
+        y, aux, moe_st, counters = moe.moe_apply(
+            lp["moe"], st.get("moe", {}), y, cfg, train=train)
+        if moe_st:
+            st = {"moe": moe_st}
     else:
         y = mlp.mlp_apply(lp["mlp"], y, cfg)
-    return x + y, kv, aux
+    return x + y, kv, aux, st, counters
 
 
-def _layer_decode(lp, x, cfg: ModelConfig, pos, cache, *, enc_out=None):
+def _layer_decode(lp, x, cfg: ModelConfig, pos, cache, *, enc_out=None,
+                  st=None):
     """Single-token decode. Returns (x, new_cache)."""
     if cfg.family == "ssm":
         h, new_cache = mamba2.mamba_decode(
@@ -148,8 +157,8 @@ def _layer_decode(lp, x, cfg: ModelConfig, pos, cache, *, enc_out=None):
         )
         x = x + h
     y = _norm(cfg, lp["ffn_norm"], x)
-    if cfg.num_experts > 0:
-        y, _ = moe.moe_apply(lp["moe"], y, cfg)
+    if "moe" in lp:
+        y = moe.moe_apply(lp["moe"], (st or {}).get("moe", {}), y, cfg)[0]
     else:
         y = mlp.mlp_apply(lp["mlp"], y, cfg)
     return x + y, new_cache
@@ -268,9 +277,8 @@ def init(key, cfg: ModelConfig):
         params["enc_pos_embed"] = nn.truncated_normal_init(0.02)(
             keys[3], (cfg.encoder_len, cfg.d_model), dtype
         )
-        enc_cfg = dataclasses.replace(cfg, num_experts=0)
         params["encoder"] = _stack_init(
-            lambda k: _layer_init(k, enc_cfg, dtype=dtype),
+            lambda k: _layer_init(k, cfg, dtype=dtype)[0],
             keys[4], cfg.encoder_layers,
         )
         params["enc_norm"] = _norm_init(cfg, dtype)
@@ -280,21 +288,24 @@ def init(key, cfg: ModelConfig):
         kseg = jax.random.fold_in(keys[5], si)
         if seg[0] == "run":
             cross = cfg.family == "encdec"
-            segs[f"seg{si}"] = _stack_init(
-                lambda k: _layer_init(k, cfg, dtype=dtype, cross=cross),
+            segs[f"seg{si}"], st = _stack_init(
+                lambda k: _layer_init(k, cfg, dtype=dtype, cross=cross,
+                                      ffn=seg[2]),
                 kseg, seg[1],
             )
+            if st:
+                state[f"seg{si}"] = st
         elif seg[0] == "hybrid":
             ssm_cfg = dataclasses.replace(cfg, family="ssm")
             unit_init = lambda k: _stack_init(
-                lambda kk: _layer_init(kk, ssm_cfg, dtype=dtype),
+                lambda kk: _layer_init(kk, ssm_cfg, dtype=dtype)[0],
                 k, cfg.hybrid_pattern,
             )
             segs[f"seg{si}"] = _stack_init(unit_init, kseg, seg[1])
             dense_cfg = dataclasses.replace(cfg, family="dense")
             params["shared_attn"] = _layer_init(
                 keys[6], dense_cfg, dtype=dtype
-            )
+            )[0]
         else:
             _, idx, kind = seg
             segs[f"seg{si}"], st = _memory_layer_init(kseg, cfg, kind,
@@ -364,10 +375,10 @@ def _run_encoder(params, batch, cfg: ModelConfig):
     x = x + params["enc_pos_embed"][:s][None].astype(x.dtype)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32),
                                  (x.shape[0], s))
-    enc_cfg = dataclasses.replace(cfg, num_experts=0, attn_impl="dense")
+    enc_cfg = dataclasses.replace(cfg, attn_impl="dense")
 
     def body(x, lp):
-        y, _, _ = _layer_full(lp, x, enc_cfg, positions, causal=False)
+        y = _layer_full(lp, x, enc_cfg, positions, causal=False)[0]
         return y, None
 
     x, _ = _scan_layers(_maybe_remat(body, cfg), x, params["encoder"], cfg)
@@ -378,8 +389,33 @@ def forward(params, state, batch, cfg: ModelConfig, *, train: bool = False,
             collect_access: bool = False):
     """Full-sequence forward. Returns (logits, new_state, aux_loss)
     [+ memory-access dict {seg: (idx, w)} when collect_access=True]."""
+    logits, new_state, aux, accesses, _ = _forward(
+        params, state, batch, cfg, train=train, collect_access=collect_access)
+    if collect_access:
+        return logits, new_state, aux, accesses
+    return logits, new_state, aux
+
+
+def _merge_counters(total, layer):
+    """Expert-layer counters summed over layers, but the largest expert
+    load, which is the most over layers."""
+    if layer is None:
+        return total
+    layer = {"routed_copies": jnp.sum(layer["routed_copies"]),
+             "max_expert_load": jnp.max(layer["max_expert_load"])}
+    if total is None:
+        return layer
+    return {"routed_copies": total["routed_copies"] + layer["routed_copies"],
+            "max_expert_load": jnp.maximum(total["max_expert_load"],
+                                           layer["max_expert_load"])}
+
+
+def _forward(params, state, batch, cfg: ModelConfig, *, train: bool,
+             collect_access: bool):
+    """(logits, new_state, aux_loss, accesses, expert counters or None)."""
     causal = cfg.objective == "clm"
     accesses: dict[str, Any] = {}
+    counters = None
     x, positions = _embed_inputs(params, batch, cfg)
     enc_kv = None
     if cfg.family == "encdec":
@@ -392,7 +428,8 @@ def forward(params, state, batch, cfg: ModelConfig, *, train: bool = False,
         name = f"seg{si}"
         sp = params["segments"][name]
         if seg[0] == "run":
-            def body(x, lp):
+            def body(x, lp_st):
+                lp, st = lp_st
                 enc = None
                 if enc_kv is not None:
                     b, t = enc_kv.shape[:2]
@@ -403,12 +440,17 @@ def forward(params, state, batch, cfg: ModelConfig, *, train: bool = False,
                         b, t, cfg.num_kv_heads, cfg.head_dim
                     )
                     enc = (ek, ev)
-                y, _, aux = _layer_full(lp, x, cfg, positions,
-                                        causal=causal, enc_out=enc)
-                return y, aux
+                y, _, aux, st, cnt = _layer_full(
+                    lp, x, cfg, positions, causal=causal, enc_out=enc,
+                    st=st, train=train)
+                return y, (aux, st, cnt)
 
-            x, auxs = _scan_layers(_maybe_remat(body, cfg), x, sp, cfg)
+            x, (auxs, st, cnt) = _scan_layers(
+                _maybe_remat(body, cfg), x, (sp, state.get(name, {})), cfg)
             aux_total = aux_total + auxs.sum()
+            counters = _merge_counters(counters, cnt)
+            if st:
+                new_state[name] = st
         elif seg[0] == "hybrid":
             shared = params["shared_attn"]
             ssm_cfg = dataclasses.replace(cfg, family="ssm")
@@ -416,12 +458,12 @@ def forward(params, state, batch, cfg: ModelConfig, *, train: bool = False,
 
             def unit(x, up):
                 def mbody(x, lp):
-                    y, _, _ = _layer_full(lp, x, ssm_cfg, positions,
-                                          causal=True)
+                    y = _layer_full(lp, x, ssm_cfg, positions,
+                                    causal=True)[0]
                     return y, None
                 x, _ = _scan_layers(mbody, x, up, cfg)
-                y, _, _ = _layer_full(shared, x, dense_cfg, positions,
-                                      causal=True)
+                y = _layer_full(shared, x, dense_cfg, positions,
+                                causal=True)[0]
                 return y, None
 
             x, _ = _scan_layers(_maybe_remat(unit, cfg), x, sp, cfg)
@@ -440,9 +482,7 @@ def forward(params, state, batch, cfg: ModelConfig, *, train: bool = False,
         logits = x @ params["embed"]["embedding"].astype(x.dtype).T
     else:
         logits = nn.dense(params["lm_head"], x)
-    if collect_access:
-        return logits, new_state or state, aux_total, accesses
-    return logits, new_state or state, aux_total
+    return logits, new_state or state, aux_total, accesses, counters
 
 
 # ---------------------------------------------------------------------------
@@ -454,15 +494,9 @@ def loss_fn(params, state, batch, cfg: ModelConfig, *, train: bool = True,
     """Scalar loss + aux.  `collect_access=True` additionally returns the
     memory-access dict {seg: (idx, w)} from the forward pass (the
     telemetry train step scatter-adds `idx` into its usage counters)."""
-    if collect_access:
-        logits, new_state, aux, accesses = forward(
-            params, state, batch, cfg, train=train, collect_access=True
-        )
-    else:
-        logits, new_state, aux = forward(
-            params, state, batch, cfg, train=train
-        )
-        accesses = None
+    logits, new_state, aux, accesses, counters = _forward(
+        params, state, batch, cfg, train=train, collect_access=collect_access
+    )
     labels = batch["labels"]
     valid = labels != IGNORE
     safe_labels = jnp.where(valid, labels, 0)
@@ -472,6 +506,9 @@ def loss_fn(params, state, batch, cfg: ModelConfig, *, train: bool = True,
     xent = -(tok_ll * valid).sum() / denom
     loss = xent + cfg.router_aux_weight * aux
     metrics = {"xent": xent, "aux": aux, "ntokens": denom}
+    if counters is not None:
+        # program counters of the expert layers (`moe.moe_apply`)
+        metrics.update({f"moe.{k}": v for k, v in counters.items()})
     if collect_access:
         return loss, (new_state, metrics, accesses)
     return loss, (new_state, metrics)
@@ -490,6 +527,9 @@ def _attn_cache_len(cfg: ModelConfig, max_len: int) -> int:
 def cache_shapes(cfg: ModelConfig, batch: int, max_len: int):
     """Nested dict of (shape, dtype) — basis for zeros-init AND dry-run
     ShapeDtypeStructs (no allocation)."""
+    if cfg.attention == "mla":
+        raise NotImplementedError(
+            f"{cfg.name}: latent attention has no decode cache")
     dtype = jnp.dtype(cfg.dtype)
     t = _attn_cache_len(cfg, max_len)
     kvd = (batch, t, cfg.num_kv_heads, cfg.head_dim)
@@ -580,11 +620,11 @@ def decode_step(params, state, tokens, pos, cache, cfg: ModelConfig,
         c = cache[name]
         if seg[0] == "run":
             def body(x, lp_c):
-                lp, ci = lp_c
-                y, co = _layer_decode(lp, x, cfg, pos, ci)
+                lp, st, ci = lp_c
+                y, co = _layer_decode(lp, x, cfg, pos, ci, st=st)
                 return y, co
 
-            x, co = _scan_layers(body, x, (sp, c), cfg)
+            x, co = _scan_layers(body, x, (sp, state.get(name, {}), c), cfg)
             new_cache[name] = co
         elif seg[0] == "hybrid":
             shared = params["shared_attn"]
@@ -751,14 +791,17 @@ def prefill(params, state, batch, cfg: ModelConfig, max_len: int):
                 x, (hf, convt) = _scan_layers(body, x, sp, cfg)
                 cache[name] = {"ssm": hf, "conv": convt}
             else:
-                def body(x, lp):
+                def body(x, lp_st):
+                    lp, st = lp_st
                     enc = _enc_proj(lp)
-                    y, kv, _ = _layer_full(lp, x, cfg, positions,
-                                           causal=True, enc_out=enc)
+                    y, kv, _, _, _ = _layer_full(lp, x, cfg, positions,
+                                                 causal=True, enc_out=enc,
+                                                 st=st)
                     out = (kv[0], kv[1]) + ((enc[0], enc[1]) if enc else ())
                     return y, out
 
-                x, kvs = _scan_layers(body, x, sp, cfg)
+                x, kvs = _scan_layers(body, x, (sp, state.get(name, {})),
+                                      cfg)
                 k_new, v_new = _fill_kv_cache(kvs[0], kvs[1], cfg, t_attn, s)
                 cache[name]["k"] = k_new
                 cache[name]["v"] = v_new
@@ -776,8 +819,8 @@ def prefill(params, state, batch, cfg: ModelConfig, max_len: int):
                     return y, (hf, convt)
 
                 x, (hf, convt) = _scan_layers(mbody, x, up, cfg)
-                y, kv, _ = _layer_full(shared, x, dense_cfg, positions,
-                                       causal=True)
+                y, kv = _layer_full(shared, x, dense_cfg, positions,
+                                    causal=True)[:2]
                 return y, (hf, convt, kv[0], kv[1])
 
             x, (hf, convt, k_new, v_new) = _scan_layers(unit, x, sp, cfg)

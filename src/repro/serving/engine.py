@@ -222,6 +222,11 @@ class ServeEngine:
                 f"continuous batching supports decoder-only families; "
                 f"{cfg.name} is {cfg.family}"
             )
+        if cfg.attention == "mla":
+            raise ValueError(
+                f"{cfg.name} uses latent attention (mla), which has no "
+                f"decode cache yet; it can be trained, not served"
+            )
         self.state = state
         self.engine_cfg = engine_cfg
         self.controller = controller

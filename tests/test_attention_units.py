@@ -62,10 +62,11 @@ def test_gqa_matches_mha_when_kv_repeated(rng):
 
 def test_layer_plan_structures():
     cfg = configs.get_config("yi-9b")
-    assert transformer.layer_plan(cfg) == [("run", 48)]
+    assert transformer.layer_plan(cfg) == [("run", 48, "mlp")]
     cfg = configs.with_lram(cfg, 20)
     plan = transformer.layer_plan(cfg)
-    assert plan == [("run", 24), ("memory", 24, "lram"), ("run", 23)]
+    assert plan == [("run", 24, "mlp"), ("memory", 24, "lram"),
+                    ("run", 23, "mlp")]
     z = configs.get_config("zamba2-2.7b")
     assert transformer.layer_plan(z) == [("hybrid", 9)]
 

@@ -20,13 +20,15 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from repro import configs, optim
 from repro.core import indexing
+from repro.distributed import context, sharding
 from repro.kernels import e8_lookup, gather_interp, tiered_gather
+from repro.launch.mesh import make_mesh
 from repro.launch.train import build_train_step
-from repro.models import transformer
+from repro.models import moe, transformer
 
 LOG2_ROWS, M, TOP_K = 20, 64, 32
 TRAIN_BATCH, TRAIN_SEQ, HEADS = 8, 128, 32   # lram-bert-medium: w/16 heads
@@ -36,8 +38,8 @@ STORAGE = {"fp32": jnp.float32, "int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One device of a described v5e 2x2 host, with the persistent
+def v5e_2x2():
+    """A described v5e 2x2 host (four chips), with the persistent
     compilation cache off (a compile for a described chip is written to
     the cache but cannot be read back without one)."""
     from jax.experimental import topologies
@@ -55,10 +57,16 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
             compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    """One device of the described host."""
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _shape(sharding, shape, dtype):
@@ -154,3 +162,36 @@ def test_pallas_train_step_compiles(one_chip, monkeypatch):
                      text)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
+
+
+@pytest.mark.parametrize("experts", [8, 2])
+def test_expert_layer_compiles_sharded_over_model(v5e_2x2, monkeypatch,
+                                                  experts):
+    """Mixtral's expert layer at its published widths, forward and
+    backward, over a 1 x 4 mesh of the described host: 8 experts, two a
+    chip, or 2 experts, a quarter of each one's hidden width a chip.  The
+    grouped products compile to Mosaic kernels inside the shard_map, and no
+    chip gathers the expert weights it does not hold."""
+    monkeypatch.setattr(moe, "interpret_mode", lambda: False)
+    cfg = dataclasses.replace(configs.get_config("mixtral-8x7b"),
+                              num_experts=experts, experts_held=experts)
+    mesh = make_mesh((1, 4), ("data", "model"), devices=v5e_2x2.devices)
+    params, state = jax.eval_shape(
+        lambda: moe.moe_init(jax.random.PRNGKey(0), cfg))
+    specs = sharding.param_pspecs({"moe": params}, mesh)["moe"]
+    params = jax.tree.map(lambda s, p: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=NamedSharding(mesh, p)), params, specs)
+    x = _shape(NamedSharding(mesh, jax.sharding.PartitionSpec()),
+               (1, 256, cfg.d_model), jnp.float32)
+
+    def loss(p, x):
+        return jnp.sum(moe.moe_apply(p, state, x, cfg, train=True)[0])
+
+    monkeypatch.setattr(context, "_MESH", mesh)
+    compiled = _compile(jax.grad(loss, argnums=(0, 1)), params, x,
+                        kernel="gmm")
+    text = compiled.as_text()
+    assert re.search(r"%tgmm(\.\d+)? = .*tpu_custom_call", text)
+    stack = "f32[%d,%d,%d]" % (experts, cfg.d_model, cfg.d_ff)
+    gathers = [ln for ln in text.splitlines() if "all-gather" in ln]
+    assert not [ln for ln in gathers if stack in ln], gathers
