@@ -23,7 +23,8 @@ Axes:
   | ``pallas`` (DMA gather TPU kernels, interpret mode on CPU).
 
 Backends self-register: ``repro.kernels.ref`` / ``repro.kernels.
-gather_interp`` / ``repro.kernels.tiered_gather`` register gather kernels,
+scatter_add`` / ``repro.kernels.gather_interp`` /
+``repro.kernels.tiered_gather`` register gather kernels,
 ``repro.memstore.interp`` registers the ``tiered`` placement, and
 ``repro.distributed.sharded_lram`` registers ``sharded`` and
 ``sharded-tiered``.  :func:`resolve` lazy-imports the provider module for
@@ -167,7 +168,7 @@ _PLACEMENT_PROVIDERS = {
 # see repro.kernels.tiered_gather)
 _KERNEL_IMPLS: dict[tuple[str, str], Callable] = {}
 _KERNEL_PROVIDERS = {
-    ("reference", "fp32"): "repro.kernels.ref",
+    ("reference", "fp32"): "repro.kernels.scatter_add",
     ("reference", "quant"): "repro.kernels.ref",
     ("pallas", "fp32"): "repro.kernels.gather_interp",
     ("pallas", "quant"): "repro.kernels.gather_interp",

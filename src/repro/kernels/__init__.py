@@ -11,13 +11,17 @@ Public surface:
   * `repro.kernels.tiered_gather` — the same gather through the tiered
     store's shard->slot indirection (`tiered_gather_pallas`, quantized
     `tiered_gather_quant_pallas`, jnp references)
+  * `repro.kernels.scatter_add`   — the value table's gradient: sorted,
+    block-owned scatter-add (`scatter_add_rows`, kernel `lram_scatter_add`),
+    shared by every fp32 gather's backward; `gather_rows`, the jnp gather
+    with that backward
   * `repro.kernels.ops`           — `lram_lookup`: query + gather fused
     behind one custom_vjp (sparse scatter-add backward), and the legacy
     `make_interp_impl` callable hook (deprecated)
   * `repro.kernels.ref`           — jnp references for every kernel
 
-`gather_interp` and `ref` register the "pallas" / "reference" kernel
-cells of the lookup-plan registry, and `tiered_gather` the indirected
+`gather_interp`, `scatter_add` (fp32) and `ref` (quantized) register
+the "pallas" / "reference" kernel cells of the lookup-plan registry, and `tiered_gather` the indirected
 cells (`repro.core.lookup` resolves them lazily).  On the CPU backend
 the kernels run in Pallas interpret mode (`interpret_mode`); on every
 other backend they compile to Mosaic, so a TPU never interprets.

@@ -32,7 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import lookup
-from repro.kernels import interpret_mode
+from repro.kernels import interpret_mode, scatter_add
 
 _LANES = 128
 _TOKENS_PER_STEP = 8  # tokens per grid step (the output block's sublanes)
@@ -270,11 +270,8 @@ def _vjp_fwd(values, idx, w, interpret):
 def _vjp_bwd(interpret, res, g):
     values, idx, w = res
     g = g.astype(jnp.float32)
-    m = values.shape[-1]
-    flat_idx = idx.reshape(-1)
-    flat_wg = (w.astype(jnp.float32)[..., None]
-               * g[..., None, :]).reshape(-1, m)
-    dvalues = jnp.zeros(values.shape, jnp.float32).at[flat_idx].add(flat_wg)
+    dvalues = scatter_add.scatter_add_rows(values.shape[0], idx, w, g,
+                                           interpret=interpret)
     rows = jnp.take(values, idx, axis=0).astype(jnp.float32)
     dw = jnp.einsum("...m,...km->...k", g, rows)
     return (

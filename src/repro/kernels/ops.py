@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import indexing, lattice
-from repro.kernels import e8_lookup, gather_interp, ref
+from repro.kernels import e8_lookup, gather_interp, ref, scatter_add
 
 
 def _decode_index_table(spec: indexing.TorusSpec) -> None:
@@ -79,10 +79,8 @@ def _lookup_bwd(spec, top_k, use_pallas, interpret, res, g):
     values, q, idx, w = res
     g = g.astype(jnp.float32)
     # ---- d values: sparse scatter-add (the paper's backward CUDA kernel) --
-    m = values.shape[-1]
-    flat_idx = idx.reshape(-1)
-    flat_wg = (w[..., None] * g[..., None, :]).reshape(-1, m)
-    dvalues = jnp.zeros(values.shape, jnp.float32).at[flat_idx].add(flat_wg)
+    dvalues = scatter_add.scatter_add_rows(values.shape[0], idx, w, g,
+                                           interpret=interpret)
     # ---- d query via analytic dw/dq --------------------------------------
     # recover neighbor positions from indices (nearest torus image)
     pts = _points_from_indices(idx, spec)  # (..., k, 8)

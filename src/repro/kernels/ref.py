@@ -43,7 +43,8 @@ def _gather_interp_quant_ref(table, idx, w):
     return quant.gather_interp_quant(table, idx, w)
 
 
-# the "reference" kernel axis of the lookup-plan registry: plain jnp
-# gathers for fp32 tables and QuantizedTables (repro.core.lookup)
-lookup.register_kernel("reference", "fp32", gather_interp_ref)
+# the "reference" kernel axis of the lookup-plan registry: the plain jnp
+# gather for QuantizedTables (repro.core.lookup); the fp32 one is this
+# gather with the table gradient's kernel as its backward
+# (`repro.kernels.scatter_add.gather_rows`)
 lookup.register_kernel("reference", "quant", _gather_interp_quant_ref)

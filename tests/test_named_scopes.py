@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from repro import configs, data, memstore, obs, optim
+from repro.kernels import scatter_add
 from repro.launch.train import build_train_step
 from repro.memstore import TieredSpec, TieredValueStore
 from repro.models import transformer
@@ -79,6 +80,39 @@ def test_train_step_ops_carry_lookup_and_optimizer_scopes(placement,
                and n.endswith(table_backward)
                for n in names), table_backward
     assert not under("lram/overlay")  # no overlay outside the serve engine
+
+
+@pytest.mark.parametrize("placement", ["reference", "pallas"])
+def test_table_gradient_kernel_carries_gather_scope_and_name(monkeypatch,
+                                                             placement):
+    """On a chip the table gradient is the `lram_scatter_add` kernel: its
+    ops keep the forward's `lram/gather` scope and the kernel's name.  The
+    CPU runs it interpreted: the `pallas` placement's backward does so on
+    its own; the `reference` gather, which takes XLA's scatter on the CPU,
+    is asked to."""
+    if placement == "reference":
+        rows_add = scatter_add.scatter_add_rows
+        monkeypatch.setattr(
+            scatter_add, "scatter_add_rows",
+            lambda *a, interpret=None: rows_add(*a, interpret=True))
+    cfg = configs.with_placement(configs.get_smoke_config("lram-bert-small"),
+                                 placement)
+    dcfg = data.DataConfig(vocab_size=cfg.vocab_size, seq_len=8,
+                           global_batch=2, kind="facts", objective="mlm")
+    params, state = transformer.init(jax.random.PRNGKey(0), cfg)
+    batch = jax.tree.map(jnp.asarray, data.get_batch(dcfg, step=0))
+    hlo = build_train_step(cfg, optim.OptimConfig(lr=1e-3)).lower(
+        params, optim.adam_init(params), state, jnp.zeros(()), batch
+    ).compile().as_text()
+    # every computation's: the interpreted kernel is a loop
+    names = re.findall(r'op_name="((?:[^"\\]|\\.)*)"', hlo)
+    kernel = [_plain(n) for n in names
+              if n.startswith("jit(train_step)/transpose(")
+              and "lram_scatter_add" in n]
+    assert kernel and all("/lram/gather/lram_scatter_add/" in n + "/"
+                          for n in kernel)
+    assert not [n for n in names if "/gather/" in _plain(n)
+                and n.endswith("scatter-add")]
 
 
 def test_armed_span_is_a_host_event_of_a_profile(tmp_path):

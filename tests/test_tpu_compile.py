@@ -25,7 +25,7 @@ from jax.sharding import NamedSharding, SingleDeviceSharding
 from repro import configs, optim
 from repro.core import indexing
 from repro.distributed import context, sharding
-from repro.kernels import e8_lookup, gather_interp, tiered_gather
+from repro.kernels import e8_lookup, gather_interp, scatter_add, tiered_gather
 from repro.launch.mesh import make_mesh
 from repro.launch.train import build_train_step
 from repro.models import moe, transformer
@@ -131,37 +131,110 @@ def test_e8_query_kernel_compiles(one_chip):
              kernel="lram_e8_query")
 
 
+@pytest.mark.parametrize("entries", [2**21, 2**22])
+def test_scatter_add_kernel_compiles(one_chip, entries):
+    """The table gradient's kernel over sorted entries, at the train cells'
+    2,097,152 (BERT) and 4,194,304 (Moonlight) entries a step."""
+    rows = 2**LOG2_ROWS
+    b, c = scatter_add.tiles(rows, M, entries)
+    _compile(lambda i, w, g: scatter_add._block_sums(rows, b, c, i, w, g),
+             _shape(one_chip, (entries,), jnp.int32),
+             _shape(one_chip, (entries,), jnp.float32),
+             _shape(one_chip, (entries, M), jnp.float32),
+             kernel="lram_scatter_add")
+
+
+def _train_step_text(one_chip, cfg, batch_rows, mesh=None):
+    """The compiled train step: on one chip, or with the batch over the
+    data axis of `mesh` and the parameters as `sharding.param_pspecs`
+    places them (as `train.py --use-mesh` does)."""
+    params, mstate = jax.eval_shape(
+        lambda: transformer.init(jax.random.PRNGKey(0), cfg)
+    )
+    rows = (batch_rows, TRAIN_SEQ)
+    if mesh is None:
+        params = jax.tree.map(lambda s: _shape(one_chip, s.shape, s.dtype),
+                              params)
+        batch = {k: _shape(one_chip, rows, jnp.int32)
+                 for k in ("tokens", "labels")}
+        other = one_chip
+    else:
+        specs = sharding.param_pspecs(params, mesh, model_cfg=cfg)
+        params = jax.tree.map(lambda s, p: _shape(
+            NamedSharding(mesh, p), s.shape, s.dtype), params, specs)
+        data = NamedSharding(mesh, sharding.batch_pspec(mesh))
+        batch = {k: _shape(data, rows, jnp.int32)
+                 for k in ("tokens", "labels")}
+        other = NamedSharding(mesh, jax.sharding.PartitionSpec())
+    opt = jax.eval_shape(optim.adam_init, params)
+    opt = jax.tree.map(lambda s: _shape(
+        getattr(s, "sharding", None) or other, s.shape, s.dtype), opt)
+    mstate = jax.tree.map(lambda s: _shape(other, s.shape, s.dtype), mstate)
+    step = build_train_step(cfg, optim.OptimConfig(), mesh)
+    compiled = step.lower(params, opt, mstate, _shape(other, (), jnp.float32),
+                          batch).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
+    return compiled.as_text()
+
+
+def test_reference_train_step_builds_table_gradient_with_kernel(
+        one_chip, monkeypatch):
+    """The train cell's step (the `reference` placement, batch 16): the
+    table gradient is the `lram_scatter_add` kernel under the gather's
+    scope, and no scatter writes into the table's shape."""
+    monkeypatch.setattr(scatter_add, "interpret_mode", lambda: False)
+    text = _train_step_text(one_chip,
+                            configs.get_config("lram-bert-medium"), 16)
+    assert re.search(
+        r'%lram_scatter_add(\.\d+)? = .*op_name="[^"]*lram\)*/gather/', text)
+    table = "f32[%d,%d]" % (2**LOG2_ROWS, M)
+    assert not [ln for ln in text.splitlines()
+                if re.search(r"= %s\S* scatter\(" % re.escape(table), ln)]
+
+
 def test_pallas_train_step_compiles(one_chip, monkeypatch):
     """The jitted train step of the paper's model with the Pallas gather:
     the kernel is inside the step the chip runs."""
-    # the registry cell asks the backend whether to interpret; here the
+    # the registry cells ask the backend whether to interpret; here the
     # backend is the CPU, the target is the chip
+    # (the backward's scatter-add takes the gather's flag)
     monkeypatch.setattr(gather_interp, "interpret_mode", lambda: False)
     cfg = configs.get_config("lram-bert-medium")
     cfg = dataclasses.replace(
         cfg, lram=dataclasses.replace(cfg.lram, interp_impl="pallas")
     )
-    params, mstate = jax.eval_shape(
-        lambda: transformer.init(jax.random.PRNGKey(0), cfg)
-    )
-    opt = jax.eval_shape(optim.adam_init, params)
-
-    def place(tree):
-        return jax.tree.map(lambda s: _shape(one_chip, s.shape, s.dtype),
-                            tree)
-
-    batch = {k: _shape(one_chip, (TRAIN_BATCH, TRAIN_SEQ), jnp.int32)
-             for k in ("tokens", "labels")}
-    step = build_train_step(cfg, optim.OptimConfig())
-    compiled = step.lower(place(params), place(opt), place(mstate),
-                          _shape(one_chip, (), jnp.float32), batch).compile()
-    text = compiled.as_text()
+    text = _train_step_text(one_chip, cfg, TRAIN_BATCH)
     assert "tpu_custom_call" in text
-    # the kernel keeps its name, and its forward its scope, in the step
-    assert re.search(r'%lram_gather(\.\d+)? = .*op_name="[^"]*lram\)?/gather/',
-                     text)
-    mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
+    # the kernels keep their names, and their scope, in the step
+    for kernel in ("lram_gather", "lram_scatter_add"):
+        assert re.search(
+            r'%%%s(\.\d+)? = .*op_name="[^"]*lram\)*/gather/' % kernel, text)
+
+
+def test_reference_train_step_compiles_on_a_data_mesh(v5e_2x2, monkeypatch):
+    """The train cell's step over the described host as `--use-mesh` lays
+    it out (2 data x 2 model): GSPMD never partitions a Mosaic kernel, so
+    the table gradient's kernel runs in a shard_map over `data`, each chip
+    on its half of the batch's 2,097,152 entries, and one all-reduce under
+    the gather's scope adds the halves."""
+    monkeypatch.setattr(scatter_add, "interpret_mode", lambda: False)
+    mesh = make_mesh((2, 2), ("data", "model"), devices=v5e_2x2.devices)
+    monkeypatch.setattr(context, "_MESH", mesh)
+    text = _train_step_text(None, configs.get_config("lram-bert-medium"),
+                            16, mesh)
+    half = 16 * TRAIN_SEQ * HEADS * TOP_K // 2
+    table = "f32[%d,%d]" % (2**LOG2_ROWS, M)
+    kernels = [ln for ln in text.splitlines()
+               if re.search(r"%lram_scatter_add(\.\d+)? = .*tpu_custom_call",
+                            ln)]
+    assert kernels and all("s32[1,%d]" % half in ln and re.search(
+        r'op_name="[^"]*lram\)*/gather/', ln) for ln in kernels)
+    assert [ln for ln in text.splitlines()
+            if re.search(r"= %s\S* all-reduce\(" % re.escape(table), ln)
+            and re.search(r'op_name="[^"]*lram\)*/gather/', ln)]
+    assert not [ln for ln in text.splitlines()
+                if re.search(r"= %s\S* scatter\(" % re.escape(table), ln)]
 
 
 @pytest.mark.parametrize("experts", [8, 2])
